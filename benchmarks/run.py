@@ -21,6 +21,9 @@ def main() -> None:
     ap.add_argument("--json", dest="json_out", metavar="OUT.json", default=None)
     args = ap.parse_args()
     quick, json_out = args.quick, args.json_out
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         construction,
         convergence,

@@ -51,7 +51,7 @@ def run(n: int = 1 << 16, m: int | None = None, batch: int = 1 << 16):
         ("forest_alg2", _time(lambda: sf(xi))),
         ("alias", _time(lambda: sa(xi))),
         ("forest_pallas_interpret",
-         _time(lambda: ops.forest_sample(f, xi), reps=3)),
+         _time(lambda: ops.forest_sample(f, xi, use_pallas=True), reps=3)),
     ]
     return [(name, us, batch / us) for name, us in rows]
 

@@ -24,7 +24,6 @@ from repro.configs.paper_workloads import env_map_2d
 from repro.core import build_alias, build_forest, np_sample_alias, quadratic_error, sample_forest
 from repro.core.cdf import normalize_weights
 from repro.core.lds import sobol
-from repro.kernels import ops
 from repro.pool import build_alias_batched, sample_alias_batched
 from repro.spatial import Map2DSampler
 
@@ -49,7 +48,6 @@ def main() -> None:
     img = env_map_2d(h, w)
     p_flat = (img / img.sum()).ravel()
     pts = sobol(n, dims=2).astype(np.float32)
-    use_pallas = ops.use_pallas_default()
 
     # ---- forest branch: the bulk 2-D pipeline (no per-row build loop)
     sampler = Map2DSampler(img)
@@ -83,10 +81,9 @@ def main() -> None:
         np.asarray(a_rows.q, np.float64), np.asarray(a_rows.alias), pts[:, 0]
     )
     cond = np.stack([normalize_weights(img[r]) for r in range(h)])
-    tbl = build_alias_batched(jnp.asarray(cond), use_pallas=use_pallas)
+    tbl = build_alias_batched(jnp.asarray(cond))
     ca = np.asarray(sample_alias_batched(
-        tbl, jnp.asarray(ra, jnp.int32), jnp.asarray(pts[:, 1]),
-        use_pallas=use_pallas,
+        tbl, jnp.asarray(ra, jnp.int32), jnp.asarray(pts[:, 1])
     ))
     ali_counts = np.bincount(ra * w + ca, minlength=h * w).reshape(h, w)
 

@@ -101,8 +101,7 @@ print("stream-aware drain == host-oracle drain, counters bit-equal "
 #        the model entirely — pure categorical traffic, batched drain per
 #        step (params=None: no LM in the loop).
 eng = ServeEngine(params=None, cfg=None, n_slots=8, max_seq=64,
-                  prior_sampler=PooledForestSampler(n_slots=8,
-                                                    use_pallas=False))
+                  prior_sampler=PooledForestSampler(n_slots=8))
 reqs = [
     Request(rid=i, prompt=np.zeros(1, np.int64), max_new=8,
             prior=rng.random(rng.integers(4, 60)) + 1e-3)
@@ -143,9 +142,8 @@ print("alias lanes match the O(1) table oracle; forest lanes untouched")
 # Serving-side: ``method="auto"`` resolves by stream kind — a PRNG sampler
 # (MC baseline, nothing to protect) admits to alias, a QMC sampler keeps
 # the monotone forest path so the stratification survives.
-prng_sampler = PooledForestSampler(n_slots=8, use_pallas=False,
-                                   streams="prng")
-qmc_sampler = PooledForestSampler(n_slots=8, use_pallas=False)
+prng_sampler = PooledForestSampler(n_slots=8, streams="prng")
+qmc_sampler = PooledForestSampler(n_slots=8)
 print(f"auto under prng streams -> {prng_sampler.add(ws[0]).method}; "
       f"auto under qmc streams -> {qmc_sampler.add(ws[0]).method}")
 eng2 = ServeEngine(params=None, cfg=None, n_slots=8, max_seq=64,

@@ -33,7 +33,7 @@ def main() -> None:
     )
     params = init_params(jax.random.PRNGKey(0), cfg)
     sampler = TokenSampler(mode=args.mode, n_slots=args.slots,
-                           temperature=0.8, use_pallas=False)
+                           temperature=0.8)
     eng = ServeEngine(params, cfg, n_slots=args.slots, max_seq=128,
                       sampler=sampler)
 

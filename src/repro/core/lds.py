@@ -105,10 +105,13 @@ def qmc_bits24(counter, offset_bits):
 
 
 def qmc_point(counter, offset_bits):
-    """jnp twin of :func:`qmc_point_np` (exact float32 in [0, 1))."""
+    """jnp twin of :func:`qmc_point_np` (exact float32 in [0, 1)). The
+    24-bit values convert exactly through int32, which Mosaic can cast to
+    float32 (it has no uint32 -> float32 cast)."""
     import jax.numpy as jnp
 
-    return qmc_bits24(counter, offset_bits).astype(jnp.float32) * QMC_SCALE
+    bits = qmc_bits24(counter, offset_bits).astype(jnp.int32)
+    return bits.astype(jnp.float32) * QMC_SCALE
 
 
 def _sobol2_v24() -> np.ndarray:

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -76,5 +76,5 @@ def make_pod_allreduce(mesh, compress: bool = False, axis: str | None = None):
 
     return shard_map(
         reduce_shard, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )

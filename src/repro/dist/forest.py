@@ -76,7 +76,7 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cdf import (
@@ -228,12 +228,7 @@ def resolve_partition(
 
 def pallas_row_scan(rows: jax.Array) -> jax.Array:
     """Local chunk-row scan through the Pallas kernel (raw cumsum mode)."""
-    from repro.kernels.cdf_scan import cdf_scan
-
-    return cdf_scan(
-        rows, softmax=False, normalize=False,
-        interpret=jax.default_backend() != "tpu",
-    )
+    return ops.row_cumsum(rows, use_pallas=True)
 
 
 def _distributed_raw_scan(w_rows: jax.Array, axis: str, n: int, row_scan=None):
@@ -286,7 +281,7 @@ def _cdf_builder(mesh: Mesh, axis: str, n: int, row_scan):
         return finalize_cdf(_distributed_raw_scan(w_rows, axis, n, row_scan))
 
     return jax.jit(shard_map(
-        shard_fn, mesh=mesh, in_specs=P(axis), out_specs=P(), check_rep=False
+        shard_fn, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
     ))
 
 
@@ -309,10 +304,6 @@ def _device_cells(cdf: jax.Array, m: int) -> jax.Array:
     """Guide cell of every leaf, with the device's own float ops (the plan
     must agree bit-for-bit with what shard_fn computes)."""
     return _cells(lower_bounds(cdf), m)
-
-
-def _use_pallas() -> bool:
-    return ops.use_pallas_default()
 
 
 def _round_capacity(max_count: int, n: int) -> int:
@@ -405,7 +396,7 @@ def _windowed_builder(
     return jax.jit(shard_map(
         shard_fn, mesh=mesh, in_specs=(P(), P(), P(), P()),
         out_specs=(P(), P(axis), P(axis), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -457,7 +448,7 @@ def _windowed_delta_builder(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(axis), P(axis), P(), P(), P()),
         out_specs=(P(), P(axis), P(axis), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -465,7 +456,7 @@ def _separator_distances_for(cdf: jax.Array, m: int) -> jax.Array:
     """Global (n-1,) separator distances via the forest_delta kernel path
     (the Algorithm-1 per-element work; bit-identical to
     ``core.forest._separator_distances`` on the same lower bounds)."""
-    return ops.forest_delta(lower_bounds(cdf), m, use_pallas=_use_pallas())
+    return ops.forest_delta(lower_bounds(cdf), m)
 
 
 def build_forest_from_cdf_sharded(
@@ -679,8 +670,7 @@ def update_forest_sharded(
     # entry point: new distances feed the rebuild, the changed-bits mask
     # drives per-shard dirtiness.
     d_new, leaf_changed = ops.forest_delta_update(
-        lower_bounds(forest.cdf), lower_bounds(new_cdf), m,
-        use_pallas=_use_pallas(),
+        lower_bounds(forest.cdf), lower_bounds(new_cdf), m
     )
     cells_np = np.asarray(_device_cells(new_cdf, m))
     starts, counts, fresh_cap = _plan_windows(cells_np, bounds, n)
@@ -946,7 +936,7 @@ def _routed_sampler(
     return jax.jit(shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(), P(), P(), P(), P(), P(axis)),
-        out_specs=P(axis), check_rep=False,
+        out_specs=P(axis), check_vma=False,
     ))
 
 
@@ -986,7 +976,7 @@ def _sampler(mesh: Mesh, axis: str, m: int, n: int, cap: int, use_fallback: bool
     return jax.jit(shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(), P(), P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     ))
 
 

@@ -29,14 +29,18 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def gpipe(block, mesh, axis: str):
     """Build the pipelined callable. ``block(W, h) -> h`` must be shape
     preserving; ``Ws.shape[0]`` must be divisible by ``mesh.shape[axis]``."""
     S = int(mesh.shape[axis])
+    # Outside a mesh context, trace over Auto axes: an Explicit-axes mesh
+    # types every aval by it, and a caller's jit would then need the context.
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     ring = [(i, (i + 1) % S) for i in range(S)]
 
     def stage(ws, xs):
@@ -63,12 +67,13 @@ def gpipe(block, mesh, axis: str):
         L = Ws.shape[0]
         if L % S != 0:
             raise ValueError(f"layers ({L}) must divide over pipeline axis ({S})")
+        in_context = not jax.sharding.get_abstract_mesh().empty
         return shard_map(
             stage,
-            mesh=mesh,
+            mesh=mesh if in_context else auto,
             in_specs=(P(axis), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(Ws, xs)
 
     return pipelined

@@ -10,15 +10,22 @@ show batched GPU sampling wants.
 
 The formulation is **positional**, which is what makes it kernel-shaped:
 instead of compacting lights/heavies onto separate tapes (a scatter), the
-demand/supply prefixes are cumsums of *masked* per-cell terms over the
+demand/supply prefixes are prefix sums of *masked* per-cell terms over the
 original cell order, then pinned bit-flat between member cells by an
-exactly-associative ``cummax`` over member-only values (XLA's cumsum is a
-reassociated parallel scan, so a raw positional prefix can wobble by 1 ulp
-across a ``+0.0`` term). Because the pinned tapes only increase at member
-cells, a binary search over them lands directly on the ORIGINAL index of
-the covering heavy. The whole build is then two cumsums, two cummaxes,
-three fixed-trip binary searches, and elementwise selects: no scatter, no
-sort, no data-dependent shapes.
+exactly-associative prefix max over member-only values (a reassociated
+parallel scan can wobble by 1 ulp across a ``+0.0`` term). Because the
+pinned tapes only increase at member cells, a search over them lands
+directly on the ORIGINAL index of the covering heavy. The whole build is
+then two prefix sums, two prefix maxes, one rotation sweep that does the
+three searches, and elementwise selects: no scatter, no sort, no
+data-dependent shapes.
+
+Every step is something Mosaic lowers. Mosaic has no ``cumsum``/``cummax``
+and gathers only within one vreg, so the prefix ops are the log-step
+shifted combines of :mod:`repro.kernels.cdf_scan`, and each search is a
+count: on a sorted tape, ``searchsorted(a, v)`` is the number of entries
+``<= v`` (or ``< v``), accumulated while the tape rotates one lane per step
+past every query. That sweep is O(n^2 / 1024) vector ops per 8-row block.
 
 Boundary policy matches the fixed host build exactly: zero-surplus heavies
 (``n*p == 1``) supply an empty interval, owe no debt (``surplus > 0``
@@ -37,44 +44,40 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Fixed trip count for the branchless binary searches (covers any n < 2^32;
-# same convention as the forest kernels' 32-trip bisection).
-_SEARCH_TRIPS = 32
+from .cdf_scan import lane_cummax, lane_cumsum
 
 
-def _row_searchsorted(a: jax.Array, v: jax.Array, strict: bool) -> jax.Array:
-    """Per-row ``searchsorted`` with flat row-offset gathers, branchless.
+def _tape_searches(D: jax.Array, S: jax.Array, light_start: jax.Array):
+    """The three tape searches of the build, by counting in one sweep.
 
-    ``a`` (R, n) row-wise sorted, ``v`` (R, n) query per element -> (R, n)
-    int32 in [0, n]: the first in-row position where ``a > v`` (``strict``,
-    numpy's side="right") or ``a >= v`` (side="left"). Fixed ``fori_loop``
-    trips with ``lo < hi``-guarded updates, so it is Pallas-safe and
-    bit-exact against numpy (pure comparisons, no arithmetic on values)."""
-    R, n = a.shape
-    a_flat = a.reshape(-1)
-    base = (jnp.arange(R, dtype=jnp.int32) * n)[:, None]
-    lo = jnp.zeros(v.shape, jnp.int32)
-    hi = jnp.full(v.shape, n, jnp.int32)
+    ``D``/``S`` (R, n) are the pinned (non-decreasing) demand/supply tapes.
+    Returns, per cell, with ``x = S``:
+
+    * ``p_light`` = ``searchsorted(S, light_start, side="right")``;
+    * ``pj``      = ``searchsorted(D, x, side="left")``;
+    * ``Dj``      = ``D[pj]`` where ``pj < n`` (the least tape value
+      ``>= x``; ``+inf`` otherwise);
+    * ``p_nxt``   = ``searchsorted(S, x, side="right")``.
+
+    Both tapes rotate one lane per step, so after n steps every query has
+    met every tape entry. Pure comparisons and mins: exact on any backend."""
+    n = D.shape[-1]
+    inf = jnp.float32(jnp.inf)
+    zero = jnp.zeros(D.shape, jnp.int32)
 
     def body(_, state):
-        lo, hi = state
-        active = lo < hi
-        mid = (lo + hi) >> 1
-        am = jnp.take(a_flat, base + jnp.minimum(mid, n - 1))
-        go_right = (am <= v) if strict else (am < v)
-        nlo = jnp.where(go_right, mid + 1, lo)
-        nhi = jnp.where(go_right, hi, mid)
-        return jnp.where(active, nlo, lo), jnp.where(active, nhi, hi)
+        d, s, p_light, pj, dj, p_nxt = state
+        p_light = p_light + (s <= light_start).astype(jnp.int32)
+        pj = pj + (d < S).astype(jnp.int32)
+        dj = jnp.minimum(dj, jnp.where(d >= S, d, inf))
+        p_nxt = p_nxt + (s <= S).astype(jnp.int32)
+        d = jnp.roll(d, 1, axis=-1)
+        s = jnp.roll(s, 1, axis=-1)
+        return d, s, p_light, pj, dj, p_nxt
 
-    lo, _ = jax.lax.fori_loop(0, _SEARCH_TRIPS, body, (lo, hi))
-    return lo
-
-
-def _row_take(a: jax.Array, idx: jax.Array) -> jax.Array:
-    """Row-local gather via flat offsets (the packed-table idiom)."""
-    R, n = a.shape
-    base = (jnp.arange(R, dtype=jnp.int32) * n)[:, None]
-    return jnp.take(a.reshape(-1), base + idx)
+    init = (D, S, zero, zero, jnp.full(D.shape, inf), zero)
+    _, _, p_light, pj, dj, p_nxt = jax.lax.fori_loop(0, n, body, init)
+    return p_light, pj, dj, p_nxt
 
 
 def alias_split_pack_rows(w: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -87,24 +90,25 @@ def alias_split_pack_rows(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     an alias target — so padded cells are unreachable, exactly like the
     forest arena's zero-width intervals."""
     R, n = w.shape
-    pos = jnp.arange(n, dtype=jnp.int32)[None, :]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
     wsum = jnp.sum(w, axis=-1, keepdims=True)
     npi = w / wsum * jnp.float32(n)
     light = npi < 1.0
     heavy = ~light
     dvals = jnp.where(light, 1.0 - npi, 0.0)   # per-cell demand (lights)
     svals = jnp.where(heavy, npi - 1.0, 0.0)   # per-cell surplus (heavies)
-    D = jnp.cumsum(dvals, axis=-1)             # positional demand prefix
-    S = jnp.cumsum(svals, axis=-1)             # positional supply prefix
-    # Pin tape flatness between member cells: XLA's cumsum is a reassociated
-    # parallel scan, so the prefix can wobble by 1 ulp across a +0.0 term —
-    # enough for a strict search to land on a NON-member position (a heavy's
-    # debt aliased to a light). max is exactly associative, so propagating
-    # each member's own prefix with a cummax makes flat segments bit-flat by
-    # construction; member positions keep their own cumsum value.
+    D = lane_cumsum(dvals)                     # positional demand prefix
+    S = lane_cumsum(svals)                     # positional supply prefix
+    # Pin tape flatness between member cells: the prefix sum is a
+    # reassociated parallel scan, so it can wobble by 1 ulp across a +0.0
+    # term — enough for a strict search to land on a NON-member position (a
+    # heavy's debt aliased to a light). max is exactly associative, so
+    # propagating each member's own prefix with a prefix max makes flat
+    # segments bit-flat by construction; member positions keep their own
+    # prefix value.
     ninf = jnp.float32(-jnp.inf)
-    D = jax.lax.cummax(jnp.where(light, D, ninf), axis=1)
-    S = jax.lax.cummax(jnp.where(heavy, S, ninf), axis=1)
+    D = lane_cummax(jnp.where(light, D, ninf), ninf)
+    S = lane_cummax(jnp.where(heavy, S, ninf), ninf)
     total = jnp.minimum(D[:, -1:], S[:, -1:])
     has_both = jnp.any(light, axis=-1, keepdims=True) & jnp.any(
         heavy, axis=-1, keepdims=True
@@ -112,22 +116,18 @@ def alias_split_pack_rows(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     last_heavy = jnp.maximum(
         jnp.max(jnp.where(heavy, pos, -1), axis=-1, keepdims=True), 0
     )
-
     # lights: alias = the heavy whose supply interval contains the START of
     # the light's demand interval. The positional prefix only increases at
     # positive-surplus heavies, so the first strictly-greater position IS
     # that heavy's original index (zero-surplus heavies never cross).
-    p_light = _row_searchsorted(S, D - dvals, strict=True)
-    alias_light = jnp.where(p_light < n, jnp.minimum(p_light, n - 1), last_heavy)
-
-    # heavies: where a heavy's own supply ends inside a light's demand
-    # interval it owes the remainder (debt) to the next supplying heavy.
+    # heavies: where a heavy's own supply ends (x = S) inside a light's
+    # demand interval it owes the remainder (debt) to the next supplying
+    # heavy.
     x = S
-    pj = _row_searchsorted(D, x, strict=False)
+    p_light, pj, Dj, p_nxt = _tape_searches(D, S, D - dvals)
+    alias_light = jnp.where(p_light < n, jnp.minimum(p_light, n - 1), last_heavy)
     inside = (pj < n) & (x < total) & (svals > 0.0)
-    Dj = _row_take(D, jnp.minimum(pj, n - 1))
     debt = jnp.clip(jnp.where(inside, Dj - x, 0.0), 0.0, 1.0)
-    p_nxt = _row_searchsorted(S, x, strict=True)
     nxt = jnp.where(p_nxt < n, jnp.minimum(p_nxt, n - 1), last_heavy)
     alias_heavy = jnp.where(debt > 0.0, nxt, pos)
 
@@ -148,7 +148,7 @@ def _alias_build_kernel(w_ref, q_ref, a_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def alias_build_batched(
-    weights: jax.Array, block_b: int = 8, interpret: bool = True
+    weights: jax.Array, block_b: int = 8, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array]:
     """(B, n) stacked weights -> packed ``(q, alias)`` (B, n) f32/i32 stacks.
 

@@ -54,7 +54,8 @@ def alias_sample_batched(
     dist_id: jax.Array,
     xi: jax.Array,
     block: int = 2048,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     coalesce: bool = True,
 ) -> jax.Array:
     """Bulk sampling over B stacked alias tables: ``(dist_id, xi)`` pairs

@@ -14,6 +14,11 @@ Two phases (two `pallas_call`s):
 
 Tiling: rows x vocab blocks of (R, T); T a multiple of 128 (lane width), R a
 multiple of 8 (sublanes, f32). VMEM working set = 2*R*T*4B + carries.
+
+Mosaic has no lowering for ``cumsum`` or ``cummax``, so the in-tile prefix
+ops are log-step shifted combines (:func:`lane_cumsum`, :func:`lane_cummax`):
+rolls, selects and adds in a fixed order, which give the same bits under
+Mosaic, XLA and the interpreter.
 """
 from __future__ import annotations
 
@@ -25,6 +30,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _lane_scan(x: jax.Array, combine, fill) -> jax.Array:
+    """Inclusive scan along the last axis by log-step shifted combines
+    (Hillis-Steele): step ``s`` combines each lane with the lane ``s`` to its
+    left, lanes ``< s`` with ``fill``."""
+    T = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    s = 1
+    while s < T:
+        x = combine(x, jnp.where(lane >= s, jnp.roll(x, s, axis=-1), fill))
+        s *= 2
+    return x
+
+
+def lane_cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the last axis, in the same association on
+    every backend (the kernels and their jnp references share it)."""
+    return _lane_scan(x, jnp.add, jnp.zeros((), x.dtype))
+
+
+def lane_cummax(x: jax.Array, fill) -> jax.Array:
+    """Inclusive prefix max along the last axis (exact in any order)."""
+    return _lane_scan(x, jnp.maximum, jnp.asarray(fill, x.dtype))
 
 
 def _stats_kernel(x_ref, m_ref, s_ref, mc_ref, sc_ref, *, softmax: bool):
@@ -66,7 +95,7 @@ def _scan_kernel(x_ref, m_ref, s_ref, o_ref, c_ref, *, softmax: bool):
         e = jnp.exp(x - m_ref[...]) / s_ref[...]
     else:
         e = x / s_ref[...]
-    c = jnp.cumsum(e, axis=-1) + c_ref[...]
+    c = lane_cumsum(e) + c_ref[...]
     o_ref[...] = c
     c_ref[...] = c[:, -1:]
 
@@ -81,7 +110,8 @@ def cdf_scan(
     normalize: bool = True,
     block_rows: int = 8,
     block_cols: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """(B, V) logits (softmax=True) or non-negative weights (False) ->
     (B, V) inclusive CDF rows, last element ~1.0 (leading 0 omitted).

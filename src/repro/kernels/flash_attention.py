@@ -77,7 +77,8 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]

@@ -35,7 +35,7 @@ def _delta_kernel(a_ref, b_ref, o_ref, *, m: int):
 
 @functools.partial(jax.jit, static_argnames=("m", "block", "interpret"))
 def forest_delta(
-    data: jax.Array, m: int, block: int = 1024, interpret: bool = True
+    data: jax.Array, m: int, block: int = 1024, *, interpret: bool
 ) -> jax.Array:
     """data (n,) f32 increasing lower bounds -> (n-1,) uint32 distances."""
     n = data.shape[0]
@@ -69,7 +69,8 @@ def forest_delta_update(
     data_new: jax.Array,
     m: int,
     block: int = 1024,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Algorithm-1 re-work for a weight update, in one elementwise pass.
 

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.lds import QMC_SCALE, qmc_bits24
+from repro.core.lds import qmc_point
 
 
 def _forest_kernel(
@@ -112,7 +112,7 @@ def _forest_batched_kernel(
             cf_ref, fb_ref, did_ref, ctr_ref, off_ref, o_ref, xi_ref = rest
         else:
             did_ref, ctr_ref, off_ref, o_ref, xi_ref = rest
-        xi = qmc_bits24(ctr_ref[...], off_ref[...]).astype(jnp.float32) * QMC_SCALE
+        xi = qmc_point(ctr_ref[...], off_ref[...])
         xi_ref[...] = xi
     else:
         if fb:
@@ -185,7 +185,8 @@ def forest_sample_batched(
     fallback: jax.Array | None = None,
     depth: int = 40,
     block: int = 2048,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     coalesce: bool = True,
 ) -> jax.Array:
     """Bulk sampling over B stacked forests: ``(dist_id, xi)`` pairs (Q,) ->
@@ -259,7 +260,8 @@ def forest_sample_batched_streams(
     fallback: jax.Array | None = None,
     depth: int = 40,
     block: int = 2048,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     coalesce: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """The stream-aware bulk drain: per-lane QMC state in, draws out.
@@ -327,7 +329,8 @@ def forest_sample(
     fallback: jax.Array | None = None,
     depth: int = 40,
     block: int = 2048,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Batch Algorithm 2. xi (B,) -> interval indices (B,) int32.
 

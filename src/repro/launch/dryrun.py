@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS_EXTRA", "")
-)
-# ^ MUST precede any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds ShapeDtypeStruct inputs (zero allocation), attaches
@@ -23,6 +16,7 @@ import argparse
 import contextlib
 import dataclasses
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -31,6 +25,16 @@ from pathlib import Path
 # step builders) is imported inside function bodies. Importing this module
 # must stay cheap and dependency-free so `--list`, the report tooling, and
 # `tests/test_imports.py` cannot be taken down by a broken subsystem.
+
+
+def force_host_devices() -> None:
+    """512 fake host devices for the production meshes. Called from
+    ``main`` only, before jax's backend starts (it locks the device count on
+    first use) — never at import, where it would leak into the importer."""
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS_EXTRA", "")
+    )
 
 
 def _opt_struct(params_sds, opt_dtype: str):
@@ -253,6 +257,7 @@ def run_cell(
 
 
 def main() -> None:
+    force_host_devices()
     import repro.configs as configs
     from repro.launch.shapes import SHAPES, cell_matrix
 

@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Per-op collective breakdown for one cell: the §Perf microscope.
 
   PYTHONPATH=src python -m repro.launch.inspect_collectives --arch granite-3-8b --shape train_4k
@@ -23,9 +21,11 @@ def main() -> None:
     ap.add_argument("--remat", default="dots")
     args = ap.parse_args()
 
+    from repro.launch.dryrun import force_host_devices
+
+    force_host_devices()
     import repro.configs as configs
     from repro.launch import roofline as R
-    from repro.launch.dryrun import run_cell  # noqa: F401 (env already set)
     from repro.launch.mesh import make_production_mesh
     from repro.launch.shapes import SHAPES
 

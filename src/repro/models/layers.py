@@ -114,12 +114,9 @@ def _sdpa(q, k, v, cfg: ModelConfig, causal: bool, q_off: int | jax.Array = 0):
 def attention(p: Params, cfg: ModelConfig, x, positions, causal=True) -> jax.Array:
     q, k, v = _qkv(p, cfg, x, positions, rope=True)
     if cfg.attn_impl == "flash":
-        from repro.kernels.flash_attention import flash_attention
+        from repro.kernels import ops
 
-        out = flash_attention(
-            q, k, v, causal=causal,
-            interpret=jax.default_backend() != "tpu",
-        )
+        out = ops.flash_attention(q, k, v, causal=causal)
     else:
         out = _sdpa(q, k, v, cfg, causal)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))

@@ -350,9 +350,7 @@ class ForestPool:
                 fill = np.full((bpad - len(idxs), size), 1.0, np.float32)
                 stack = np.concatenate([stack, fill])
             if meth == "alias":
-                q, a = ops.alias_build_batched(
-                    jnp.asarray(stack), use_pallas=ops.use_pallas_default()
-                )
+                q, a = ops.alias_build_batched(jnp.asarray(stack))
                 self._write_alias_rows(
                     ar, rows, BatchedAlias(q[: len(idxs)], a[: len(idxs)])
                 )
@@ -428,9 +426,7 @@ class ForestPool:
             if np.array_equal(new_row.view(np.uint32), old_row.view(np.uint32)):
                 sc.skips += 1
                 return
-            q, a = ops.alias_build_batched(
-                jnp.asarray(new_row[None]), use_pallas=ops.use_pallas_default()
-            )
+            q, a = ops.alias_build_batched(jnp.asarray(new_row[None]))
             self._write_alias_rows(sc, [handle.row], BatchedAlias(q, a))
             sc.rebuilds += 1
             return
@@ -446,8 +442,7 @@ class ForestPool:
             sc.delta_skips += 1
             return
         d_new, _ = ops.forest_delta_update(
-            lower_bounds(old_cdf), lower_bounds(new_cdf), sc.m,
-            use_pallas=ops.use_pallas_default(),
+            lower_bounds(old_cdf), lower_bounds(new_cdf), sc.m
         )
         built = _rebuild_row(new_cdf, d_new, sc.m)
         self._write_rows(sc, [handle.row], BatchedForest(
@@ -631,7 +626,7 @@ class ForestPool:
         hi = np.asarray([handles[q].n - 1 for q in qs], np.int64)
         out[qs] = np.minimum(np.asarray(idx)[: len(qs)], hi).astype(np.int32)
 
-    def sample(self, handles, xi, use_pallas: bool = True,
+    def sample(self, handles, xi, use_pallas: bool | None = None,
                coalesce: bool = True, guard: bool = False) -> np.ndarray:
         """Bulk mixed-batch drain from host uniforms: draw q resolves
         ``xi[q]`` in ``handles[q]``'s distribution. One batched kernel
@@ -670,7 +665,7 @@ class ForestPool:
         return out
 
     def sample_streams(self, handles, slots, streams,
-                       use_pallas: bool = True, coalesce: bool = True,
+                       use_pallas: bool | None = None, coalesce: bool = True,
                        return_xi: bool = False,
                        guard: bool = False) -> np.ndarray:
         """The stream-aware bulk drain: draw q resolves ``slots[q]``'s next

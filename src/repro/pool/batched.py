@@ -168,7 +168,7 @@ def sample_forest_batched(
     forest: BatchedForest,
     dist_id: jax.Array,
     xi: jax.Array,
-    use_pallas: bool = True,
+    use_pallas: bool | None = None,
 ) -> jax.Array:
     """Bulk mixed-batch sampling: draw ``q`` resolves uniform ``xi[q]`` in
     distribution ``dist_id[q]``'s tree — one launch for the whole batch.
@@ -179,7 +179,8 @@ def sample_forest_batched(
     return ops.forest_sample_batched(forest, dist_id, xi, use_pallas=use_pallas)
 
 
-def build_alias_batched(weights: jax.Array, use_pallas: bool = True) -> BatchedAlias:
+def build_alias_batched(weights: jax.Array,
+                        use_pallas: bool | None = None) -> BatchedAlias:
     """The fused batched alias build: (B, n) weights -> B packed tables in
     one program (``kernels.alias_build``; the ref and kernel paths share
     the row core, so both are bit-identical). Rows with exact dyadic
@@ -195,7 +196,7 @@ def sample_alias_batched(
     table: BatchedAlias,
     dist_id: jax.Array,
     xi: jax.Array,
-    use_pallas: bool = True,
+    use_pallas: bool | None = None,
 ) -> jax.Array:
     """Bulk mixed-batch alias drain: draw ``q`` resolves uniform ``xi[q]``
     in distribution ``dist_id[q]``'s packed table — O(1) per lane, one

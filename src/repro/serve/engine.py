@@ -85,7 +85,7 @@ class ServeEngine:
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.on_fault = on_fault
-        self.sampler = sampler or TokenSampler(n_slots=n_slots, use_pallas=False)
+        self.sampler = sampler or TokenSampler(n_slots=n_slots)
         self.prior_sampler = prior_sampler
         self.prior_handles: dict[int, Any] = {}  # slot -> pool Handle
         self.spatial_sampler = spatial_sampler
@@ -186,8 +186,7 @@ class ServeEngine:
         its first texels in one bulk ``sample_map`` drain."""
         if self.spatial_sampler is None:
             self.spatial_sampler = SpatialSampler(
-                admitted[0][1].prior2d, n_slots=self.n_slots,
-                use_pallas=False,
+                admitted[0][1].prior2d, n_slots=self.n_slots
             )
         kept = []
         for s, req in admitted:
@@ -220,9 +219,7 @@ class ServeEngine:
         per touched size class) and draws its first tokens in one batched
         drain."""
         if self.prior_sampler is None:
-            self.prior_sampler = PooledForestSampler(
-                n_slots=self.n_slots, use_pallas=False
-            )
+            self.prior_sampler = PooledForestSampler(n_slots=self.n_slots)
         try:
             hs = self.prior_sampler.add_many(
                 [r.prior for _, r in admitted],

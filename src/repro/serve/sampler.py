@@ -531,7 +531,7 @@ class PooledForestSampler:
     non-monotone alias map."""
 
     def __init__(self, n_slots: int = 64, seed: int = 0, min_class: int = 8,
-                 m: int | None = None, use_pallas: bool = True,
+                 m: int | None = None, use_pallas: bool | None = None,
                  device_streams: bool = True, streams: str = "qmc",
                  policy: str = "reject"):
         from repro.pool import ForestPool  # lazy: serve stays importable
@@ -630,7 +630,11 @@ class PooledForestSampler:
 
 class TokenSampler:
     def __init__(self, mode: str = "inverse_qmc", n_slots: int = 64,
-                 temperature: float = 1.0, seed: int = 0, use_pallas: bool = True):
+                 temperature: float = 1.0, seed: int = 0,
+                 use_pallas: bool | None = None):
+        # use_pallas: None takes the kernels.ops policy (the compiled
+        # fused-CDF and sampling kernels on a TPU, their XLA references
+        # elsewhere); True/False ask for the kernel or the reference.
         assert mode in ("inverse_qmc", "inverse_rng", "alias")
         self.mode = mode
         self.temperature = temperature

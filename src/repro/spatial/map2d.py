@@ -55,8 +55,7 @@ import jax.numpy as jnp
 from repro.core.cdf import build_cdf, lower_bounds, normalize_weights
 from repro.core.forest import RadixForest, forest_from_cdf
 from repro.core.forest2d import build_forest_rows
-from repro.kernels import ops, ref
-from repro.kernels.forest_sample import forest_sample as _forest_sample_kernel
+from repro.kernels import ops
 from repro.pool.arena import _pow2_at_least
 from repro.robust.validate import check_policy, sanitize_weights
 from repro.pool.batched import BatchedForest, batched_from_row_forest
@@ -81,34 +80,18 @@ class _CondClass:
         self.skips = 0               # update_map: bit-unchanged rows
 
 
-def _marginal_descend(forest: RadixForest, xi, use_pallas: bool,
-                      degenerate: bool):
-    """Shared-marginal Algorithm 2 with host-tracked degenerate flag (the
-    jit-safe core of ``ops.forest_sample``, which instead syncs on the
-    device fallback bits and so cannot live inside a fused program)."""
-    cf = forest.cell_first if degenerate else None
-    fb = forest.fallback if degenerate else None
-    if not use_pallas:
-        return ref.ref_forest_sample(
-            forest.cdf, forest.table, forest.left, forest.right, xi, cf, fb
-        )
-    return _forest_sample_kernel(
-        forest.cdf, forest.table, forest.left, forest.right, xi, cf, fb,
-        interpret=jax.default_backend() != "tpu",
-    )
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("use_pallas", "marg_degenerate", "cond_degenerate",
                      "coalesce"),
 )
 def _fused_sample(marg: RadixForest, cond: BatchedForest, slot_of, widths,
-                  u, v, *, use_pallas: bool, marg_degenerate: bool,
+                  u, v, *, use_pallas: bool | None, marg_degenerate: bool,
                   cond_degenerate: bool, coalesce: bool):
     """The single-class pipeline as ONE program: marginal descent on ``u``,
     slot lookup, batched conditional descent on ``v``, true-width clip."""
-    row = _marginal_descend(marg, u, use_pallas, marg_degenerate)
+    row = ops.forest_sample(marg, u, use_pallas=use_pallas,
+                            degenerate=marg_degenerate)
     col = ops.forest_sample_batched(
         cond, slot_of[row], v, use_pallas=use_pallas,
         degenerate=cond_degenerate, coalesce=coalesce,
@@ -179,9 +162,7 @@ class Map2DSampler:
         self.min_class = min_class
         self.fallback_slack = fallback_slack
         self.coalesce = coalesce
-        self.use_pallas = (
-            ops.use_pallas_default() if use_pallas is None else use_pallas
-        )
+        self.use_pallas = use_pallas
         self.sharded = sharded
         self.routed = routed
         self.last_drain: dict | None = None
@@ -260,8 +241,9 @@ class Map2DSampler:
             return self._DF.sample_sharded(
                 self._marginal, u, mesh=self._mesh, routed=self.routed
             )
-        return _marginal_descend(
-            self._marginal, u, self.use_pallas, self._marg_degenerate
+        return ops.forest_sample(
+            self._marginal, u, use_pallas=self.use_pallas,
+            degenerate=self._marg_degenerate,
         )
 
     def sample_map(self, points2d):

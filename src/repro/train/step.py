@@ -75,7 +75,8 @@ def make_train_step(
     return train_step
 
 
-def make_serve_step(cfg: ModelConfig, use_pallas: bool = False, temperature: float = 1.0):
+def make_serve_step(cfg: ModelConfig, use_pallas: bool | None = None,
+                    temperature: float = 1.0):
     """Returns serve_step(params, cache, token, pos, xi[, enc_out])
     -> (next_token (B,), cache). xi: per-slot uniforms (B,) — QMC streams
     from the serving scheduler keep the monotone warp stratified."""

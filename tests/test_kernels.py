@@ -258,7 +258,26 @@ def test_flash_attention_ragged_causal():
     q = jnp.asarray(rng.normal(0, 1, (1, 100, 2, 32)), jnp.float32)
     k = jnp.asarray(rng.normal(0, 1, (1, 100, 2, 32)), jnp.float32)
     v = jnp.asarray(rng.normal(0, 1, (1, 100, 2, 32)), jnp.float32)
-    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.ref_flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_ops_implementation_policy(monkeypatch, backend):
+    """One dispatch point: None takes the policy (kernels compiled on a TPU,
+    references elsewhere), True off a TPU is the interpreter, and the ops
+    Mosaic refuses stay on XLA on every backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    tpu = backend == "tpu"
+    for op in ops.OPS:
+        refused = op in ops.XLA_ONLY
+        assert ops.implementation(op, False) == "xla"
+        assert ops.implementation(op) == ("pallas" if tpu and not refused else "xla")
+        want = "xla" if tpu and refused else ("pallas" if tpu else "interpret")
+        assert ops.implementation(op, True) == want
+    assert ops.implementations() == {op: ops.implementation(op) for op in ops.OPS}
+    with pytest.raises(ValueError):
+        ops.implementation("no_such_op")
