@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.trace import scope, span
+
 from .bits import DIST_SENTINEL, float_to_bits, np_xor_distance
 from .cdf import build_cdf, lower_bounds, np_build_cdf
 
@@ -186,114 +188,117 @@ def _build_cell_trees(
     node_offset = jnp.int32(node_offset)
     m_owned = jnp.int32(m_local if m_owned is None else m_owned)
 
-    # Ownership; out-of-range scatter indices route to m_local and drop
-    # (negative indices would wrap, so they must be rewritten, not dropped).
-    loc = cells - cell_lo
-    owned_leaf = (loc >= 0) & (loc < m_owned)
-    loc_safe = jnp.where(owned_leaf, loc, m_local)
+    with scope("forest.cell_trees"):
+        # Ownership; out-of-range scatter indices route to m_local and drop
+        # (negative indices would wrap, so they must be rewritten, not dropped).
+        loc = cells - cell_lo
+        owned_leaf = (loc >= 0) & (loc < m_owned)
+        loc_safe = jnp.where(owned_leaf, loc, m_local)
 
-    grid = (cell_lo + jnp.arange(m_local, dtype=jnp.int32)).astype(
-        jnp.float32
-    ) / jnp.float32(m)
-    cell_first = (
-        jnp.searchsorted(data, grid, side="right").astype(jnp.int32) - 1
-    )
-    cell_first = jnp.clip(cell_first + node_offset, 0, n_total - 1)
-
-    counts = jnp.zeros((m_local,), jnp.int32).at[loc_safe].add(1, mode="drop")
-    first_leaf = jnp.full((m_local,), n, jnp.int32).at[loc_safe].min(
-        jnp.arange(n, dtype=jnp.int32), mode="drop"
-    )
-    f_safe = jnp.clip(first_leaf, 0, n - 1)       # window-relative
-    left_overlap = data[f_safe] > grid
-    overlap = jnp.where(counts > 0, counts + left_overlap.astype(jnp.int32), 1)
-
-    left = jnp.full((n,), INVALID, jnp.int32)
-    right = jnp.full((n,), INVALID, jnp.int32)
-    leaf_parent = jnp.full((n,), -1, jnp.int32)   # window-relative node ids
-    node_parent = jnp.full((n,), -1, jnp.int32)
-
-    if n > 1:
-        dL, _L, dR, _R = _nearest_greater(d)
-        k = jnp.arange(n - 1, dtype=jnp.int32)
-        in_cell = d != sentinel
-        owned_k = owned_leaf[:-1]    # separator k lives in cell cells[k]
-        is_root = in_cell & (dL == sentinel) & (dR == sentinel)
-        par_is_L = dL <= dR
-        parent_sep = jnp.where(par_is_L, _L, _R)
-        parent_node = parent_sep + 1              # window-relative slot
-        node_id = k + 1 + node_offset             # global reference value
-
-        # Internal non-root separators -> child of parent separator's node.
-        wr = owned_k & in_cell & ~is_root & par_is_L    # right child of L
-        wl = owned_k & in_cell & ~is_root & ~par_is_L   # left child of R
-        right = right.at[jnp.where(wr, parent_node, n)].set(node_id, mode="drop")
-        left = left.at[jnp.where(wl, parent_node, n)].set(node_id, mode="drop")
-        node_parent = node_parent.at[
-            jnp.where(owned_k & in_cell & ~is_root, k + 1, n)
-        ].set(parent_node, mode="drop")
-
-        # Cell roots -> right child of the cell's root slot.
-        root_slot = first_leaf[
-            jnp.clip(loc[jnp.clip(k, 0, n - 1)], 0, m_local - 1)
-        ]
-        wroot = owned_k & is_root
-        right = right.at[jnp.where(wroot, root_slot, n)].set(node_id, mode="drop")
-        node_parent = node_parent.at[jnp.where(wroot, k + 1, n)].set(
-            root_slot, mode="drop"
+        grid = (cell_lo + jnp.arange(m_local, dtype=jnp.int32)).astype(
+            jnp.float32
+        ) / jnp.float32(m)
+        cell_first = (
+            jnp.searchsorted(data, grid, side="right").astype(jnp.int32) - 1
         )
+        cell_first = jnp.clip(cell_first + node_offset, 0, n_total - 1)
 
-    # Leaves.
-    i = jnp.arange(n, dtype=jnp.int32)
-    dl = jnp.where(i > 0, d[jnp.clip(i - 1, 0)], sentinel) if n > 1 else jnp.full(
-        (n,), sentinel, jnp.uint32
-    )
-    dr = jnp.where(i < n - 1, d[jnp.clip(i, 0, max(n - 2, 0))], sentinel) if n > 1 else (
-        jnp.full((n,), sentinel, jnp.uint32)
-    )
-    lone = (dl == sentinel) & (dr == sentinel)
-    lpar_is_left = dl <= dr
-    lparent = jnp.where(lpar_is_left, i, i + 1)   # node slot (sep i-1 -> node i)
-    leaf_ref = ~(i + node_offset)
-    wr = owned_leaf & ~lone & lpar_is_left
-    wl = owned_leaf & ~lone & ~lpar_is_left
-    right = right.at[jnp.where(wr, lparent, n)].set(leaf_ref, mode="drop")
-    left = left.at[jnp.where(wl, lparent, n)].set(leaf_ref, mode="drop")
-    # Lone leaf: it is its cell's entire tree -> right child of its root slot
-    # (which is itself).
-    right = right.at[jnp.where(owned_leaf & lone, i, n)].set(leaf_ref, mode="drop")
-    leaf_parent = jnp.where(lone, i, lparent)
+        counts = jnp.zeros((m_local,), jnp.int32).at[loc_safe].add(1, mode="drop")
+        first_leaf = jnp.full((m_local,), n, jnp.int32).at[loc_safe].min(
+            jnp.arange(n, dtype=jnp.int32), mode="drop"
+        )
+        f_safe = jnp.clip(first_leaf, 0, n - 1)       # window-relative
+        left_overlap = data[f_safe] > grid
+        overlap = jnp.where(counts > 0, counts + left_overlap.astype(jnp.int32), 1)
 
-    # Manual left child of every root slot: the interval overlapping the cell
-    # from the left (unreachable when the cell starts exactly at a bound).
-    nonempty = counts > 0
-    manual = ~jnp.maximum(f_safe + node_offset - 1, 0)
-    left = left.at[jnp.where(nonempty, f_safe, n)].set(manual, mode="drop")
+        left = jnp.full((n,), INVALID, jnp.int32)
+        right = jnp.full((n,), INVALID, jnp.int32)
+        leaf_parent = jnp.full((n,), -1, jnp.int32)   # window-relative node ids
+        node_parent = jnp.full((n,), -1, jnp.int32)
 
-    # Guide table.
-    table = jnp.where(
-        counts == 0,
-        ~cell_first,
-        jnp.where(overlap == 1, ~(f_safe + node_offset), f_safe + node_offset),
-    ).astype(jnp.int32)
+        if n > 1:
+            dL, _L, dR, _R = _nearest_greater(d)
+            k = jnp.arange(n - 1, dtype=jnp.int32)
+            in_cell = d != sentinel
+            owned_k = owned_leaf[:-1]    # separator k lives in cell cells[k]
+            is_root = in_cell & (dL == sentinel) & (dR == sentinel)
+            par_is_L = dL <= dR
+            parent_sep = jnp.where(par_is_L, _L, _R)
+            parent_node = parent_sep + 1              # window-relative slot
+            node_id = k + 1 + node_offset             # global reference value
+
+            # Internal non-root separators -> child of parent separator's node.
+            wr = owned_k & in_cell & ~is_root & par_is_L    # right child of L
+            wl = owned_k & in_cell & ~is_root & ~par_is_L   # left child of R
+            right = right.at[jnp.where(wr, parent_node, n)].set(node_id, mode="drop")
+            left = left.at[jnp.where(wl, parent_node, n)].set(node_id, mode="drop")
+            node_parent = node_parent.at[
+                jnp.where(owned_k & in_cell & ~is_root, k + 1, n)
+            ].set(parent_node, mode="drop")
+
+            # Cell roots -> right child of the cell's root slot.
+            root_slot = first_leaf[
+                jnp.clip(loc[jnp.clip(k, 0, n - 1)], 0, m_local - 1)
+            ]
+            wroot = owned_k & is_root
+            right = right.at[jnp.where(wroot, root_slot, n)].set(node_id, mode="drop")
+            node_parent = node_parent.at[jnp.where(wroot, k + 1, n)].set(
+                root_slot, mode="drop"
+            )
+
+        # Leaves.
+        i = jnp.arange(n, dtype=jnp.int32)
+        dl = jnp.where(i > 0, d[jnp.clip(i - 1, 0)], sentinel) if n > 1 else jnp.full(
+            (n,), sentinel, jnp.uint32
+        )
+        dr = jnp.where(i < n - 1, d[jnp.clip(i, 0, max(n - 2, 0))], sentinel) if n > 1 else (
+            jnp.full((n,), sentinel, jnp.uint32)
+        )
+        lone = (dl == sentinel) & (dr == sentinel)
+        lpar_is_left = dl <= dr
+        lparent = jnp.where(lpar_is_left, i, i + 1)   # node slot (sep i-1 -> node i)
+        leaf_ref = ~(i + node_offset)
+        wr = owned_leaf & ~lone & lpar_is_left
+        wl = owned_leaf & ~lone & ~lpar_is_left
+        right = right.at[jnp.where(wr, lparent, n)].set(leaf_ref, mode="drop")
+        left = left.at[jnp.where(wl, lparent, n)].set(leaf_ref, mode="drop")
+        # Lone leaf: it is its cell's entire tree -> right child of its root slot
+        # (which is itself).
+        right = right.at[jnp.where(owned_leaf & lone, i, n)].set(leaf_ref, mode="drop")
+        leaf_parent = jnp.where(lone, i, lparent)
+
+        # Manual left child of every root slot: the interval overlapping the cell
+        # from the left (unreachable when the cell starts exactly at a bound).
+        nonempty = counts > 0
+        manual = ~jnp.maximum(f_safe + node_offset - 1, 0)
+        left = left.at[jnp.where(nonempty, f_safe, n)].set(manual, mode="drop")
+
+        # Guide table.
+        table = jnp.where(
+            counts == 0,
+            ~cell_first,
+            jnp.where(overlap == 1, ~(f_safe + node_offset), f_safe + node_offset),
+        ).astype(jnp.int32)
 
     # Traversal depth per leaf -> per-cell fallback flags (paper's degenerate-
     # tree guard: rebuild-as-balanced becomes a per-cell bisection mode).
-    depth = jnp.zeros((n,), jnp.int32)
-    anc = leaf_parent
-    for _ in range(_DEPTH_ITERS):
-        live = anc >= 0
-        depth = depth + live.astype(jnp.int32)
-        anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
-    depth = depth + 1  # the leaf resolution step itself
+    with scope("forest.depth_guard"):
+        depth = jnp.zeros((n,), jnp.int32)
+        anc = leaf_parent
+        for _ in range(_DEPTH_ITERS):
+            live = anc >= 0
+            depth = depth + live.astype(jnp.int32)
+            anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
+        depth = depth + 1  # the leaf resolution step itself
 
-    cell_depth = jnp.zeros((m_local,), jnp.int32).at[loc_safe].max(
-        depth, mode="drop"
-    )
-    allowed = jnp.ceil(jnp.log2(jnp.maximum(overlap, 2).astype(jnp.float32)))
-    fallback = (overlap > 1) & (
-        cell_depth > allowed.astype(jnp.int32) + fallback_slack
-    )
+        cell_depth = jnp.zeros((m_local,), jnp.int32).at[loc_safe].max(
+            depth, mode="drop"
+        )
+        allowed = jnp.ceil(
+            jnp.log2(jnp.maximum(overlap, 2).astype(jnp.float32)))
+        fallback = (overlap > 1) & (
+            cell_depth > allowed.astype(jnp.int32) + fallback_slack
+        )
     return left, right, table, cell_first, fallback
 
 
@@ -312,10 +317,11 @@ def forest_from_cdf(
     """
     cdf = jnp.asarray(cdf, jnp.float32)
     n = cdf.shape[0] - 1
-    data = lower_bounds(cdf)  # (n,)
-    cells = _cells(data, m)
-    if d is None:
-        d = _separator_distances(data, cells)
+    with scope("forest.separators"):
+        data = lower_bounds(cdf)  # (n,)
+        cells = _cells(data, m)
+        if d is None:
+            d = _separator_distances(data, cells)
     left, right, table, cf, fallback = _build_cell_trees(
         data, d, cells, m=m, cell_lo=0, m_local=m, fallback_slack=fallback_slack
     )
@@ -333,7 +339,11 @@ def build_forest_from_cdf(
 
 def build_forest(weights: jax.Array, m: int, fallback_slack: int = 2) -> RadixForest:
     """Weights -> CDF (parallel scan) -> forest. The end-to-end build."""
-    return build_forest_from_cdf(build_cdf(weights), m, fallback_slack)
+    with span("repro.build_forest"):
+        with span("repro.build_cdf"):
+            cdf = build_cdf(weights)
+        with span("repro.build_forest_from_cdf"):
+            return build_forest_from_cdf(cdf, m, fallback_slack)
 
 
 # ---------------------------------------------------------------------------

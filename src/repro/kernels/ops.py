@@ -15,13 +15,19 @@ everywhere) takes the policy. ``True`` off a TPU means the interpreter.
 
 :data:`XLA_ONLY` names the ops whose kernels Mosaic refuses; they run as
 ``"xla"`` on every backend, whatever the caller asks for on a TPU.
+
+Every op runs under the device scope ``ops.<op>`` (:mod:`repro.trace`), so
+its device time carries that name in a profile whichever implementation ran.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.forest import RadixForest
+from repro.trace import scope, span
 
 from . import ref
 from .alias_build import alias_build_batched as _alias_build_batched
@@ -79,17 +85,31 @@ def _kernel(op: str, use_pallas: bool | None):
     return None if impl == "xla" else impl == "interpret"
 
 
+def _scoped(fn):
+    """Runs the public op ``fn`` under the device scope ``ops.<name>``, so
+    its device time carries the op's name whichever implementation runs."""
+    name = f"ops.{fn.__name__}"
+
+    @functools.wraps(fn)
+    def op(*args, **kwargs):
+        with scope(name):
+            return fn(*args, **kwargs)
+    return op
+
+
 def _degenerate_tables(forest, degenerate: bool | None):
     """The side tables for degenerate (tied-weight) cells, or ``None`` when
     no cell is flagged. ``degenerate=None`` asks the device (one blocking
     reduction; callers that track it host-side pass it and stay jit-safe)."""
     if degenerate is None:
-        degenerate = bool(jax.device_get(forest.fallback.any()))
+        with span("repro.ops.degenerate_read"):
+            degenerate = bool(jax.device_get(forest.fallback.any()))
     if not degenerate:
         return None, None
     return forest.cell_first, forest.fallback
 
 
+@_scoped
 def fused_cdf(x: jax.Array, softmax: bool = True,
               use_pallas: bool | None = None) -> jax.Array:
     """(B, V) logits/weights -> (B, V) inclusive CDF rows."""
@@ -99,6 +119,7 @@ def fused_cdf(x: jax.Array, softmax: bool = True,
     return _cdf_scan(x, softmax=softmax, interpret=interpret)
 
 
+@_scoped
 def row_cumsum(rows: jax.Array, use_pallas: bool | None = None) -> jax.Array:
     """(R, L) -> raw inclusive row prefix sums (no normalisation): the local
     scan of the distributed CDF build."""
@@ -108,6 +129,7 @@ def row_cumsum(rows: jax.Array, use_pallas: bool | None = None) -> jax.Array:
     return _cdf_scan(rows, softmax=False, normalize=False, interpret=interpret)
 
 
+@_scoped
 def sample_rows(cdf_rows: jax.Array, xi: jax.Array,
                 use_pallas: bool | None = None) -> jax.Array:
     """Per-row inverse CDF: (B, V) x (B, k) -> (B, k) int32 indices."""
@@ -117,6 +139,7 @@ def sample_rows(cdf_rows: jax.Array, xi: jax.Array,
     return _sample_rows(cdf_rows, xi, interpret=interpret)
 
 
+@_scoped
 def forest_sample(forest: RadixForest, xi: jax.Array,
                   use_pallas: bool | None = None,
                   degenerate: bool | None = None) -> jax.Array:
@@ -139,6 +162,7 @@ def forest_sample(forest: RadixForest, xi: jax.Array,
     )
 
 
+@_scoped
 def forest_sample_batched(
     forest, dist_id: jax.Array, xi: jax.Array,
     use_pallas: bool | None = None, degenerate: bool | None = None,
@@ -169,6 +193,7 @@ def forest_sample_batched(
     )
 
 
+@_scoped
 def forest_sample_batched_streams(
     forest, dist_id: jax.Array, counter: jax.Array, offset_bits: jax.Array,
     use_pallas: bool | None = None, degenerate: bool | None = None,
@@ -196,6 +221,7 @@ def forest_sample_batched_streams(
     )
 
 
+@_scoped
 def alias_build_batched(
     weights: jax.Array, use_pallas: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
@@ -210,6 +236,7 @@ def alias_build_batched(
     return _alias_build_batched(weights, interpret=interpret)
 
 
+@_scoped
 def alias_sample_batched(
     table, dist_id: jax.Array, xi: jax.Array,
     use_pallas: bool | None = None, coalesce: bool = True,
@@ -233,6 +260,7 @@ def alias_sample_batched(
     )
 
 
+@_scoped
 def forest_delta(data: jax.Array, m: int,
                  use_pallas: bool | None = None) -> jax.Array:
     """Separator distances for forest construction."""
@@ -242,6 +270,7 @@ def forest_delta(data: jax.Array, m: int,
     return _forest_delta(data, m, interpret=interpret)
 
 
+@_scoped
 def forest_delta_update(data_old: jax.Array, data_new: jax.Array, m: int,
                         use_pallas: bool | None = None):
     """New separator distances + changed-leaf-bits mask for a weight update."""
@@ -251,6 +280,7 @@ def forest_delta_update(data_old: jax.Array, data_new: jax.Array, m: int,
     return _forest_delta_update(data_old, data_new, m, interpret=interpret)
 
 
+@_scoped
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     use_pallas: bool | None = None) -> jax.Array:

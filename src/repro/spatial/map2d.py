@@ -59,6 +59,27 @@ from repro.kernels import ops
 from repro.pool.arena import _pow2_at_least
 from repro.robust.validate import check_policy, sanitize_weights
 from repro.pool.batched import BatchedForest, batched_from_row_forest
+from repro.trace import count, span
+
+
+def _to_device(*arrays) -> list[jax.Array]:
+    """Host arrays onto the device, counted in ``host.bytes_in``."""
+    out = [jnp.asarray(a) for a in arrays]
+    count("host.bytes_in", sum(a.nbytes for a in out))
+    return out
+
+
+def _to_host(*arrays) -> list[np.ndarray]:
+    """Device arrays onto the host, counted in ``host.bytes_out``."""
+    out = [np.asarray(a) for a in arrays]
+    count("host.bytes_out", sum(a.nbytes for a in out))
+    return out
+
+
+def _flagged(forest) -> bool:
+    """The host read of whether ``forest`` flagged a degenerate cell."""
+    with span("repro.map2d.fallback_read"):
+        return bool(_to_host(forest.fallback.any())[0])
 
 
 class _CondClass:
@@ -255,7 +276,15 @@ class Map2DSampler:
         ``dist_id`` = the row's class slot (the launch count lands in
         ``self.last_drain``, the structural fact the benchmarks pin).
         Elementwise identical to the per-row ``build_forest`` +
-        ``sample_forest`` reference over the padded rows."""
+        ``sample_forest`` reference over the padded rows.
+
+        Host spans ``repro.map2d.copy_in`` / ``dispatch`` / ``wait`` /
+        ``copy_out`` mark the host's part of a drain; the copies count in
+        ``host.bytes_in`` / ``host.bytes_out`` (:mod:`repro.trace`)."""
+        with span("repro.map2d.sample"):
+            return self._sample_map(points2d)
+
+    def _sample_map(self, points2d):
         if isinstance(points2d, tuple):
             u, v = points2d
             u = np.asarray(u, np.float32)
@@ -267,22 +296,35 @@ class Map2DSampler:
             u, v = pts[:, 0], pts[:, 1]
         if self._fused:
             cls = next(iter(self.classes.values()))
-            row, col = _fused_sample(
-                self._marginal, cls.forest, self._slot_j, self._widths_j,
-                jnp.asarray(u), jnp.asarray(v),
-                use_pallas=self.use_pallas,
-                marg_degenerate=self._marg_degenerate,
-                cond_degenerate=cls.degenerate,
-                coalesce=self.coalesce,
-            )
+            with span("repro.map2d.copy_in"):
+                u_dev, v_dev = _to_device(u, v)
+            with span("repro.map2d.dispatch"):
+                row, col = _fused_sample(
+                    self._marginal, cls.forest, self._slot_j,
+                    self._widths_j, u_dev, v_dev,
+                    use_pallas=self.use_pallas,
+                    marg_degenerate=self._marg_degenerate,
+                    cond_degenerate=cls.degenerate,
+                    coalesce=self.coalesce,
+                )
+            with span("repro.map2d.wait"):
+                jax.block_until_ready((row, col))
+            with span("repro.map2d.copy_out"):
+                row, col = _to_host(row, col)
             self.last_drain = dict(
                 launches=1, fused=True, classes=[cls.width],
                 marginal="fused",
             )
-            return (np.asarray(row, np.int32), np.asarray(col, np.int32),
-                    u, v)
+            return row, col, u, v
 
-        rows = np.asarray(self._sample_marginal(jnp.asarray(u)), np.int64)
+        with span("repro.map2d.copy_in"):
+            (u_dev,) = _to_device(u)
+        with span("repro.map2d.dispatch"):
+            rows = self._sample_marginal(u_dev)
+        with span("repro.map2d.wait"):
+            rows.block_until_ready()
+        with span("repro.map2d.copy_out"):
+            rows = _to_host(rows)[0].astype(np.int64)
         cols = np.empty(len(rows), np.int32)
         touched = []
         for wc in np.unique(self._class_of[rows]):
@@ -292,15 +334,20 @@ class Map2DSampler:
             didp = np.full(qpad, -1, np.int32)
             didp[: len(qs)] = self._slot_of[rows[qs]]
             vp = np.pad(v[qs], (0, qpad - len(qs)))
-            idx = ops.forest_sample_batched(
-                cls.forest, jnp.asarray(didp), jnp.asarray(vp),
-                use_pallas=self.use_pallas, degenerate=cls.degenerate,
-                coalesce=self.coalesce,
-            )
+            with span("repro.map2d.copy_in"):
+                didp_dev, vp_dev = _to_device(didp, vp)
+            with span("repro.map2d.dispatch"):
+                idx = ops.forest_sample_batched(
+                    cls.forest, didp_dev, vp_dev,
+                    use_pallas=self.use_pallas, degenerate=cls.degenerate,
+                    coalesce=self.coalesce,
+                )
+            with span("repro.map2d.wait"):
+                idx.block_until_ready()
+            with span("repro.map2d.copy_out"):
+                (idx,) = _to_host(idx)
             hi = (self.widths[rows[qs]] - 1).astype(np.int64)
-            cols[qs] = np.minimum(
-                np.asarray(idx)[: len(qs)], hi
-            ).astype(np.int32)
+            cols[qs] = np.minimum(idx[: len(qs)], hi).astype(np.int32)
             touched.append(int(wc))
         self.last_drain = dict(
             launches=len(touched), fused=False, classes=touched,
@@ -321,7 +368,14 @@ class Map2DSampler:
         through the delta kernel (sharded: ``update_forest_sharded``), with
         its own CDF-bits skip. Returns stats: ``rebuilt_rows`` /
         ``skipped_rows`` (the O(dirty rows) structural witness),
-        ``cond_launches``, ``marginal_rebuilt``."""
+        ``cond_launches``, ``marginal_rebuilt``.
+
+        Runs under the host span ``repro.map2d.update``; its pulls and
+        uploads count in ``host.bytes_out`` / ``host.bytes_in``."""
+        with span("repro.map2d.update"):
+            return self._update_map(delta_rows, delta)
+
+    def _update_map(self, delta_rows: dict, delta: bool) -> dict:
         by_class: dict[int, list[int]] = {}
         for r, w in delta_rows.items():
             r = int(r)
@@ -347,9 +401,11 @@ class Map2DSampler:
             cls = self.classes[wc]
             slots = np.asarray([self._slot_of[r] for r in rids], np.int64)
             stack = np.stack([self._padded_cond(r, wc) for r in rids])
-            new_cdf = _cdf_stack(jnp.asarray(stack))
-            old_bits = np.asarray(cls.cdf_rows)[slots].view(np.uint32)
-            new_bits = np.asarray(new_cdf).view(np.uint32)
+            new_cdf = _cdf_stack(*_to_device(stack))
+            with span("repro.map2d.cdf_pull"):
+                old_rows, new_rows = _to_host(cls.cdf_rows, new_cdf)
+            old_bits = old_rows[slots].view(np.uint32)
+            new_bits = new_rows.view(np.uint32)
             dirty = np.flatnonzero((old_bits != new_bits).any(axis=1))
             stats["skipped_rows"] += len(rids) - len(dirty)
             cls.skips += len(rids) - len(dirty)
@@ -361,19 +417,18 @@ class Map2DSampler:
             sel = np.concatenate(
                 [dirty, np.zeros(dpad - len(dirty), np.int64)]
             )
-            cdf_dirty = new_cdf[jnp.asarray(sel)]
+            sel_dev, idx, dirty_dev = _to_device(
+                sel, slots[dirty].astype(np.int32), dirty)
+            cdf_dirty = new_cdf[sel_dev]
             rf = build_forest_rows(cdf_dirty, m=wc,
                                    fallback_slack=self.fallback_slack)
             built = batched_from_row_forest(rf, cdf_dirty)
-            idx = jnp.asarray(slots[dirty], jnp.int32)
             cls.forest = BatchedForest(
                 *(a.at[idx].set(b[: len(dirty)])
                   for a, b in zip(cls.forest, built))
             )
-            cls.cdf_rows = cls.cdf_rows.at[idx].set(
-                new_cdf[jnp.asarray(dirty)]
-            )
-            cls.degenerate = bool(jax.device_get(cls.forest.fallback.any()))
+            cls.cdf_rows = cls.cdf_rows.at[idx].set(new_cdf[dirty_dev])
+            cls.degenerate = _flagged(cls.forest)
             cls.rebuilds += len(dirty)
             stats["rebuilt_rows"] += len(dirty)
             stats["cond_launches"] += 1
@@ -388,12 +443,12 @@ class Map2DSampler:
             stats["marginal_rebuilt"] = bool(mst["rebuilt"])
             stats["marginal_shards"] = mst
         else:
-            new_cdf = build_cdf(jnp.asarray(marg_w))
+            new_cdf = build_cdf(*_to_device(marg_w))
             old_cdf = self._marginal.cdf
-            if np.array_equal(
-                np.asarray(old_cdf).view(np.uint32),
-                np.asarray(new_cdf).view(np.uint32),
-            ):
+            with span("repro.map2d.cdf_pull"):
+                old_host, new_host = _to_host(old_cdf, new_cdf)
+            if np.array_equal(old_host.view(np.uint32),
+                              new_host.view(np.uint32)):
                 return stats
             d_new, _ = ops.forest_delta_update(
                 lower_bounds(old_cdf), lower_bounds(new_cdf),
@@ -402,9 +457,7 @@ class Map2DSampler:
             self._marginal = _rebuild_marginal(
                 new_cdf, d_new, self.m_marginal
             )
-            self._marg_degenerate = bool(
-                jax.device_get(self._marginal.fallback.any())
-            )
+            self._marg_degenerate = _flagged(self._marginal)
             stats["marginal_rebuilt"] = True
         return stats
 
