@@ -11,7 +11,10 @@ is tighter on TPU than on the paper's GPUs.
 
 Gathers (``jnp.take`` from VMEM) are the honest cost: one per lane per level.
 Depth is bounded (<= ~34 for distinct float32 keys; build flags tied chains
-into fallback cells which ops.py pre-resolves), so `depth` is static.
+into fallback cells which ops.py pre-resolves), so `depth` is static: every
+kernel runs its `depth` trips. The XLA formulation in :mod:`repro.kernels.ref`
+(what ``ops`` runs for the ops in ``ops.XLA_ONLY``) instead stops at the
+deepest lane, at most `depth` trips, with the same answers.
 
 :func:`forest_sample_batched` is the multi-distribution twin (the
 ``repro.pool`` serving workload): B stacked forests resident at once, each

@@ -147,9 +147,11 @@ def forest_sample(forest: RadixForest, xi: jax.Array,
 
     When the build flagged degenerate (tied-weight) cells, both paths get the
     forest's ``cell_first``/``fallback`` side tables so those lanes
-    pre-resolve by bisection instead of running past the fixed trip count.
+    pre-resolve by bisection instead of running past the trip cap.
     Well-conditioned forests (no flagged cell — the common case) skip the
-    side tables and the 32-trip pre-resolution entirely."""
+    side tables and the pre-resolution entirely. The XLA formulation stops
+    its bisection (at most 32 trips) and its descent (at most 64) when the
+    deepest lane is done; the Pallas kernel runs its static ``depth`` trips."""
     cf, fb = _degenerate_tables(forest, degenerate)
     interpret = _kernel("forest_sample", use_pallas)
     if interpret is None:
@@ -173,13 +175,14 @@ def forest_sample_batched(
     ``forest`` is any object with the stacked ``BatchedForest`` fields
     (``repro.pool.batched.BatchedForest``; duck-typed here so the kernel
     layer never imports the pool layer). Same degenerate-cell policy as
-    :func:`forest_sample`; ``ForestPool`` tracks flagged rows host-side and
-    passes ``degenerate``, sparing the serving hot path a blocking device
-    round-trip per drain. Lanes with ``dist_id < 0`` are sentinels
-    (padding): resolved to 0 without walking any tree. ``coalesce`` toggles
-    the kernel's bucketing pre-pass (stable sort by owning tree; elementwise
-    identical either way — the jnp reference is order-invariant and ignores
-    it)."""
+    :func:`forest_sample`, and the same loops: the XLA formulation stops at
+    the deepest lane, the Pallas kernel runs its static ``depth`` trips.
+    ``ForestPool`` tracks flagged rows host-side and passes ``degenerate``,
+    sparing the serving hot path a blocking device round-trip per drain.
+    Lanes with ``dist_id < 0`` are sentinels (padding): resolved to 0
+    without walking any tree. ``coalesce`` toggles the kernel's bucketing
+    pre-pass (stable sort by owning tree; elementwise identical either way —
+    the jnp reference is order-invariant and ignores it)."""
     cf, fb = _degenerate_tables(forest, degenerate)
     interpret = _kernel("forest_sample_batched", use_pallas)
     if interpret is None:

@@ -1,10 +1,17 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+The descent oracles are also the XLA formulation that ``kernels.ops`` runs
+for the ops in ``ops.XLA_ONLY``. Their loops stop at the deepest lane (the
+descent at most ``depth`` trips, the degenerate-cell bisection at most 32),
+where the Pallas kernels run their static ``depth`` trips.
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.bits import DIST_SENTINEL
+from repro.trace import scope
 
 
 def ref_cdf_scan(x: jax.Array, softmax: bool = True) -> jax.Array:
@@ -31,32 +38,91 @@ def ref_sample_rows(cdf_rows: jax.Array, xi: jax.Array) -> jax.Array:
     return jax.vmap(one)(cdf_rows, xi)
 
 
+def _bisect(at, cdf, xi, lo, hi, steps: int = 32):
+    """Balanced index bisection for lanes in degenerate cells: find i in
+    [lo, hi] with cdf[i] <= xi < cdf[i+1]. A lane with ``lo >= hi`` is
+    resolved and its ``lo`` moves no more, so the loop stops when every
+    lane is resolved, or after ``steps`` trips, and returns ``lo`` as the
+    fixed-trip bisection of ``core.sample`` does, beside the trips taken."""
+
+    def cond(state):
+        lo, hi, it = state
+        return jnp.any(lo < hi) & (it < steps)
+
+    def body(state):
+        lo, hi, it = state
+        with scope("descent.bisect_trip"):
+            mid = (lo + hi + 1) >> 1
+            ge = xi >= at(cdf, mid)
+            return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1), it + 1
+
+    lo, _, trips = jax.lax.while_loop(cond, body, (lo, hi, jnp.int32(0)))
+    return lo, trips
+
+
+def ref_forest_descent(
+    cdf, table, left, right, xi, cell_first=None, fallback=None,
+    depth: int = 64, dist_id=None,
+):
+    """Algorithm 2 as XLA ops, for the descent ops of both table layouts:
+    ``(idx, trips, bisect_trips)``.
+
+    1-D tables (``dist_id=None``) hold one forest; stacked (B, .) tables hold
+    B, and lane q descends row ``dist_id[q]`` with 2-D gathers (sentinel
+    lanes, ``dist_id < 0``, resolve to 0 without descending). With the
+    ``cell_first``/``fallback`` side tables, lanes in flagged cells first
+    resolve by bisection. The descent stops when its deepest lane reaches a
+    leaf, after at most ``depth`` trips; a lane at a leaf is left unchanged
+    by a trip, so the answer is that of a fixed ``depth``-trip loop. Both
+    loops return their trip counts, which the ops drop."""
+    m = table.shape[-1]
+    n = left.shape[-1]
+    g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
+    if dist_id is None:
+        def at(a, i):
+            return a[i]
+        j = table[g]
+    else:
+        raw = dist_id.astype(jnp.int32)
+        did = jnp.clip(raw, 0, table.shape[0] - 1)
+
+        def at(a, i):
+            return a[did, i]
+        j = jnp.where(raw >= 0, at(table, g), -1)  # sentinels sit at leaf ~0
+
+    bisect_trips = jnp.int32(0)
+    if cell_first is not None and fallback is not None:
+        flagged = at(fallback, g) & (j >= 0)
+        lo = at(cell_first, g)
+        hi = jnp.where(flagged, at(cell_first, g + 1), lo)
+        lo, bisect_trips = _bisect(at, cdf, xi, lo, hi)
+        j = jnp.where(flagged, ~lo, j)
+
+    def cond(state):
+        j, it = state
+        return jnp.any(j >= 0) & (it < depth)
+
+    def body(state):
+        j, it = state
+        with scope("descent.trip"):
+            jj = jnp.clip(j, 0, n - 1)
+            go_left = xi < at(cdf, jj)
+            nxt = jnp.where(go_left, at(left, jj), at(right, jj))
+            return jnp.where(j >= 0, nxt, j), it + 1
+
+    j, trips = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
+    return ~j, trips, bisect_trips
+
+
 def ref_forest_sample(
     cdf, table, left, right, xi, cell_first=None, fallback=None, depth: int = 64
 ) -> jax.Array:
     """Oracle for kernels.forest_sample.forest_sample (same optional
-    degenerate-cell pre-resolution as the kernel)."""
-    n = left.shape[0]
-    m = table.shape[0]
-    g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
-    j = table[g]
-
-    if cell_first is not None and fallback is not None:
-        # Same pre-resolution as core.sample.sample_forest — literally the
-        # same bisection, so elementwise agreement is structural.
-        from repro.core.sample import _bisect
-
-        flagged = fallback[g] & (j >= 0)
-        bal = _bisect(cdf, xi, cell_first[g], cell_first[g + 1], 32)
-        j = jnp.where(flagged, ~bal, j)
-
-    def body(_, j):
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < cdf[jj]
-        nxt = jnp.where(go_left, left[jj], right[jj])
-        return jnp.where(j >= 0, nxt, j)
-
-    return ~jax.lax.fori_loop(0, depth, body, j)
+    degenerate-cell pre-resolution as the kernel), stopping at the deepest
+    lane (:func:`ref_forest_descent`)."""
+    return ref_forest_descent(
+        cdf, table, left, right, xi, cell_first, fallback, depth
+    )[0]
 
 
 def ref_forest_sample_batched(
@@ -65,38 +131,13 @@ def ref_forest_sample_batched(
 ) -> jax.Array:
     """Oracle for kernels.forest_sample.forest_sample_batched: lane q
     descends distribution dist_id[q]'s row with 2-D gathers (same optional
-    degenerate-cell pre-resolution as the kernel). Sentinel lanes
-    (``dist_id < 0``) resolve to 0 without descending — same contract as
-    the kernel, so padded drains stay elementwise comparable."""
-    B, m = table.shape
-    n = left.shape[1]
-    raw = dist_id.astype(jnp.int32)
-    valid = raw >= 0
-    did = jnp.clip(raw, 0, B - 1)
-    g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
-    j = jnp.where(valid, table[did, g], -1)  # sentinel lanes sit at leaf ~0
-
-    if cell_first is not None and fallback is not None:
-        flagged = fallback[did, g] & (j >= 0)
-        lo = cell_first[did, g]
-        hi = cell_first[did, g + 1]
-
-        def bisect_body(_, state):
-            lo, hi = state
-            mid = (lo + hi + 1) >> 1
-            ge = xi >= cdf[did, mid]
-            return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
-
-        lo, _ = jax.lax.fori_loop(0, 32, bisect_body, (lo, hi))
-        j = jnp.where(flagged, ~lo, j)
-
-    def body(_, j):
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < cdf[did, jj]
-        nxt = jnp.where(go_left, left[did, jj], right[did, jj])
-        return jnp.where(j >= 0, nxt, j)
-
-    return ~jax.lax.fori_loop(0, depth, body, j)
+    degenerate-cell pre-resolution as the kernel), stopping at the deepest
+    lane (:func:`ref_forest_descent`). Sentinel lanes (``dist_id < 0``)
+    resolve to 0 without descending — same contract as the kernel, so padded
+    drains stay elementwise comparable."""
+    return ref_forest_descent(
+        cdf, table, left, right, xi, cell_first, fallback, depth, dist_id
+    )[0]
 
 
 def ref_forest_sample_batched_streams(

@@ -131,6 +131,153 @@ def test_forest_sample_kernel_deep_adversarial():
     assert np.all(cdf[kern_fb] <= xin) and np.all(xin < cdf[kern_fb + 1])
 
 
+def _fixed_trip_sample(cdf, table, left, right, dist_id, xi, cell_first,
+                       fallback, depth=64):
+    """The fixed-trip XLA descent that the ops ran before they stopped at
+    the deepest lane: a 32-trip bisection over every lane, then a
+    ``depth``-trip descent. Stacked (B, .) tables; one forest is a stack of
+    one row with ``dist_id = 0``."""
+    B, m = table.shape
+    n = left.shape[1]
+    raw = dist_id.astype(jnp.int32)
+    did = jnp.clip(raw, 0, B - 1)
+    g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
+    j = jnp.where(raw >= 0, table[did, g], -1)
+    flagged = fallback[did, g] & (j >= 0)
+
+    def bisect_body(_, state):
+        lo, hi = state
+        mid = (lo + hi + 1) >> 1
+        ge = xi >= cdf[did, mid]
+        return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
+
+    lo, _ = jax.lax.fori_loop(
+        0, 32, bisect_body, (cell_first[did, g], cell_first[did, g + 1]))
+    j = jnp.where(flagged, ~lo, j)
+
+    def body(_, j):
+        jj = jnp.clip(j, 0, n - 1)
+        nxt = jnp.where(xi < cdf[did, jj], left[did, jj], right[did, jj])
+        return jnp.where(j >= 0, nxt, j)
+
+    return ~jax.lax.fori_loop(0, depth, body, j)
+
+
+def _descent_weights(case):
+    """(weights, guide cells) of one forest for the descent-loop tests."""
+    if case == "zipf":
+        n = 4096
+        return (1.0 / np.arange(1, n + 1)) ** 0.75, n   # m = n
+    if case in ("spike_at_zero", "interior_ties"):
+        w = np.zeros(300)
+        if case == "spike_at_zero":
+            w[150] = 1.2                                  # 151 ties at 0.0
+        else:
+            w[0], w[299] = 1.2, 0.8                       # 299 ties at 0.6
+        return w, 16
+    if case == "dyadic_chain":
+        k = 24                                            # 24 levels, 1 cell
+        return [2.0 ** -(i + 1) for i in range(k)] + [2.0 ** -k], 1
+    assert case == "all_leaves"          # one interval per guide cell
+    return np.ones(1024), 1024
+
+
+DESCENT_CASES = ("zipf", "spike_at_zero", "interior_ties", "dyadic_chain",
+                 "all_leaves")
+
+
+@pytest.mark.parametrize(
+    "op,case",
+    [(op, case)
+     for op in ("forest_sample", "forest_sample_batched",
+                "forest_sample_batched_streams")
+     for case in DESCENT_CASES + ("sentinel_lanes",)
+     if not (op == "forest_sample" and case == "sentinel_lanes")],
+)
+def test_xla_descent_matches_fixed_trip_loop(op, case):
+    """The XLA descent stops when its deepest lane is done, yet answers
+    elementwise as the fixed 64-trip descent behind a fixed 32-trip
+    bisection does: deep chains, flagged cells, sentinel lanes, and a batch
+    in which no lane descends at all."""
+    from repro.core.lds import qmc_point
+    from repro.pool.batched import build_forest_batched
+
+    rng = np.random.default_rng(0)
+    w, m = _descent_weights("zipf" if case == "sentinel_lanes" else case)
+    w = np.asarray(w, np.float32)
+    Q = 4096
+    if op == "forest_sample":
+        f = build_forest(jnp.asarray(normalize_weights(w)), m)
+        xi = jnp.asarray(rng.random(Q), jnp.float32)
+        got = ops.forest_sample(f, xi, use_pallas=False)
+        stack = [x[None] for x in f]
+        want = _fixed_trip_sample(*stack[:4], jnp.zeros(Q, jnp.int32), xi,
+                                  *stack[4:])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    rows = [w, w[::-1], w] if case == "all_leaves" else [
+        w, w[::-1], rng.random(w.size).astype(np.float32) + 1e-3]
+    bf = build_forest_batched(jnp.asarray(np.stack(rows)), m)
+    lo = -1 if case == "sentinel_lanes" else 0
+    did = jnp.asarray(rng.integers(lo, len(rows), Q), jnp.int32)
+    if op == "forest_sample_batched":
+        xi = jnp.asarray(rng.random(Q), jnp.float32)
+        got = ops.forest_sample_batched(bf, did, xi, use_pallas=False)
+    else:
+        counter = jnp.asarray(rng.integers(0, 1 << 20, Q), jnp.uint32)
+        offset = jnp.asarray(rng.integers(0, 1 << 24, Q), jnp.uint32)
+        got, xi = ops.forest_sample_batched_streams(
+            bf, did, counter, offset, use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(xi),
+                                      np.asarray(qmc_point(counter, offset)))
+    want = _fixed_trip_sample(bf.cdf, bf.table, bf.left, bf.right, did, xi,
+                              bf.cell_first, bf.fallback)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if case == "sentinel_lanes":
+        assert np.all(np.asarray(got)[np.asarray(did) < 0] == 0)
+
+
+@pytest.mark.parametrize("case", DESCENT_CASES)
+def test_xla_descent_trip_counts(case):
+    """The descent takes as many trips as its deepest lane loads nodes
+    (``sample_forest_with_stats``); the bisection as many as its longest
+    lane needs to halve its cell's [lo, hi] down to the index it drew, at
+    most ceil(log2(span + 1)) for the widest flagged span."""
+    from repro.core import sample_forest_with_stats
+
+    w, m = _descent_weights(case)
+    f = build_forest(jnp.asarray(normalize_weights(np.asarray(w, np.float32))),
+                     m)
+    xi = jnp.asarray(np.random.default_rng(3).random(4096), jnp.float32)
+    _, trips, no_bisect = ref.ref_forest_descent(
+        f.cdf, f.table, f.left, f.right, xi)
+    visits = np.asarray(sample_forest_with_stats(f, xi)[1])
+    assert int(trips) == visits.max()
+    assert int(no_bisect) == 0
+    if case == "all_leaves":
+        assert int(trips) == 0
+
+    idx, _, bisect_trips = ref.ref_forest_descent(
+        f.cdf, f.table, f.left, f.right, xi, f.cell_first, f.fallback)
+    g = np.clip(np.floor(np.asarray(xi) * np.float32(m)).astype(np.int64),
+                0, m - 1)
+    fb, cf = np.asarray(f.fallback), np.asarray(f.cell_first)
+    flagged = fb[g] & (np.asarray(f.table)[g] >= 0)
+    lo, hi = cf[g][flagged], cf[g + 1][flagged]
+    # halve each flagged lane's [lo, hi] towards the index it drew
+    target, need = np.asarray(idx)[flagged], np.zeros(lo.size, np.int64)
+    while np.any(lo < hi):
+        mid = (lo + hi + 1) >> 1
+        need += lo < hi
+        lo, hi = (np.where(target >= mid, mid, lo),
+                  np.where(target >= mid, hi, mid - 1))
+    assert int(bisect_trips) == need.max(initial=0)
+    span = (cf[g + 1] - cf[g])[flagged].max(initial=0)
+    assert int(bisect_trips) <= int(np.ceil(np.log2(span + 1)))
+    if case in ("spike_at_zero", "interior_ties", "dyadic_chain"):
+        assert flagged.any() and int(bisect_trips) > 0
+
+
 @pytest.mark.parametrize("n,m", [(2, 1), (100, 7), (1023, 64), (8192, 4096)])
 def test_forest_delta_matches_ref(n, m):
     rng = np.random.default_rng(n)
