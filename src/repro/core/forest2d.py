@@ -46,6 +46,7 @@ from .bits import DIST_SENTINEL
 from .cdf import lower_bounds
 from .forest import _DEPTH_ITERS, INVALID, MAX_DEPTH, _nearest_greater
 from .bits import float_to_bits
+from ..trace import scope
 
 
 class RowForest(NamedTuple):
@@ -68,105 +69,108 @@ def build_forest_rows(
     R, W1 = cdf_rows.shape
     W = W1 - 1
     n = R * W
-    data = lower_bounds(cdf_rows).reshape(n)            # (R*W,) in [0,1)
-    local = jnp.clip(
-        jnp.floor(data * jnp.float32(m)).astype(jnp.int32), 0, m - 1
-    )
-    rows = jnp.repeat(jnp.arange(R, dtype=jnp.int32), W)
-    cells = rows * m + local                            # (R*W,) flat cells
     n_cells = R * m
-
-    bits = float_to_bits(data)
-    sep_raw = bits[:-1] ^ bits[1:]
-    crossing = cells[:-1] != cells[1:]                  # includes row bounds
     sentinel = jnp.uint32(DIST_SENTINEL)
-    d = jnp.where(crossing, sentinel, sep_raw)
-
-    # first interval overlapping each (row, cell): per-row searchsorted
-    grid = jnp.arange(m, dtype=jnp.float32) / jnp.float32(m)
-    cf_local = jax.vmap(
-        lambda row: jnp.searchsorted(row, grid, side="right").astype(jnp.int32) - 1
-    )(data.reshape(R, W))
-    cf = jnp.clip(cf_local, 0, W - 1) + (jnp.arange(R, dtype=jnp.int32) * W)[:, None]
-    cell_first = jnp.concatenate([cf.reshape(-1), jnp.int32(n - 1)[None]])
-
-    counts = jnp.zeros((n_cells,), jnp.int32).at[cells].add(1)
-    first_leaf = jnp.full((n_cells,), n, jnp.int32).at[cells].min(
-        jnp.arange(n, dtype=jnp.int32)
-    )
-    f_safe = jnp.clip(first_leaf, 0, n - 1)
-    cell_start = (jnp.arange(n_cells, dtype=jnp.int32) % m).astype(jnp.float32) / m
-    left_overlap = data[f_safe] > cell_start
-    overlap = jnp.where(counts > 0, counts + left_overlap.astype(jnp.int32), 1)
-
-    left = jnp.full((n,), INVALID, jnp.int32)
-    right = jnp.full((n,), INVALID, jnp.int32)
-    leaf_parent = jnp.full((n,), -1, jnp.int32)
-    node_parent = jnp.full((n,), -1, jnp.int32)
-
-    if n > 1:
-        dL, _L, dR, _R = _nearest_greater(d)
-        k = jnp.arange(n - 1, dtype=jnp.int32)
-        in_cell = ~crossing
-        is_root = in_cell & (dL == sentinel) & (dR == sentinel)
-        par_is_L = dL <= dR
-        parent_node = jnp.where(par_is_L, _L, _R) + 1
-        node_id = k + 1
-        wr = in_cell & ~is_root & par_is_L
-        wl = in_cell & ~is_root & ~par_is_L
-        right = right.at[jnp.where(wr, parent_node, n)].set(node_id, mode="drop")
-        left = left.at[jnp.where(wl, parent_node, n)].set(node_id, mode="drop")
-        node_parent = node_parent.at[
-            jnp.where(in_cell & ~is_root, k + 1, n)
-        ].set(parent_node, mode="drop")
-        root_slot = first_leaf[cells[jnp.clip(k, 0, n - 1)]]
-        right = right.at[jnp.where(is_root, root_slot, n)].set(node_id, mode="drop")
-        node_parent = node_parent.at[jnp.where(is_root, k + 1, n)].set(
-            root_slot, mode="drop"
+    with scope("forest2d.separators"):
+        data = lower_bounds(cdf_rows).reshape(n)            # (R*W,) in [0,1)
+        local = jnp.clip(
+            jnp.floor(data * jnp.float32(m)).astype(jnp.int32), 0, m - 1
         )
+        rows = jnp.repeat(jnp.arange(R, dtype=jnp.int32), W)
+        cells = rows * m + local                            # (R*W,) flat cells
 
-    i = jnp.arange(n, dtype=jnp.int32)
-    if n > 1:
-        dl = jnp.where(i > 0, d[jnp.clip(i - 1, 0)], sentinel)
-        dr = jnp.where(i < n - 1, d[jnp.clip(i, 0, max(n - 2, 0))], sentinel)
-    else:
-        dl = jnp.full((n,), sentinel, jnp.uint32)
-        dr = jnp.full((n,), sentinel, jnp.uint32)
-    lone = (dl == sentinel) & (dr == sentinel)
-    lpar_left = dl <= dr
-    lparent = jnp.where(lpar_left, i, i + 1)
-    right = right.at[jnp.where(~lone & lpar_left, lparent, n)].set(~i, mode="drop")
-    left = left.at[jnp.where(~lone & ~lpar_left, lparent, n)].set(~i, mode="drop")
-    right = right.at[jnp.where(lone, i, n)].set(~i, mode="drop")
-    leaf_parent = jnp.where(lone, i, lparent)
+        bits = float_to_bits(data)
+        sep_raw = bits[:-1] ^ bits[1:]
+        crossing = cells[:-1] != cells[1:]                  # includes row bounds
+        d = jnp.where(crossing, sentinel, sep_raw)
 
-    # manual left child: previous interval IN THE SAME ROW (clamp at row start)
-    nonempty = counts > 0
-    row_of_f = f_safe // W
-    prev_in_row = jnp.maximum(f_safe - 1, row_of_f * W)
-    left = left.at[jnp.where(nonempty, f_safe, n)].set(~prev_in_row, mode="drop")
+    with scope("forest2d.cell_trees"):
+        # first interval overlapping each (row, cell): per-row searchsorted
+        grid = jnp.arange(m, dtype=jnp.float32) / jnp.float32(m)
+        cf_local = jax.vmap(
+            lambda row: jnp.searchsorted(row, grid, side="right").astype(jnp.int32) - 1
+        )(data.reshape(R, W))
+        cf = jnp.clip(cf_local, 0, W - 1) + (jnp.arange(R, dtype=jnp.int32) * W)[:, None]
+        cell_first = jnp.concatenate([cf.reshape(-1), jnp.int32(n - 1)[None]])
 
-    table = jnp.where(
-        counts == 0, ~cell_first[:-1], jnp.where(overlap == 1, ~f_safe, f_safe)
-    ).astype(jnp.int32)
+        counts = jnp.zeros((n_cells,), jnp.int32).at[cells].add(1)
+        first_leaf = jnp.full((n_cells,), n, jnp.int32).at[cells].min(
+            jnp.arange(n, dtype=jnp.int32)
+        )
+        f_safe = jnp.clip(first_leaf, 0, n - 1)
+        cell_start = (jnp.arange(n_cells, dtype=jnp.int32) % m).astype(jnp.float32) / m
+        left_overlap = data[f_safe] > cell_start
+        overlap = jnp.where(counts > 0, counts + left_overlap.astype(jnp.int32), 1)
+
+        left = jnp.full((n,), INVALID, jnp.int32)
+        right = jnp.full((n,), INVALID, jnp.int32)
+        leaf_parent = jnp.full((n,), -1, jnp.int32)
+        node_parent = jnp.full((n,), -1, jnp.int32)
+
+        if n > 1:
+            dL, _L, dR, _R = _nearest_greater(d)
+            k = jnp.arange(n - 1, dtype=jnp.int32)
+            in_cell = ~crossing
+            is_root = in_cell & (dL == sentinel) & (dR == sentinel)
+            par_is_L = dL <= dR
+            parent_node = jnp.where(par_is_L, _L, _R) + 1
+            node_id = k + 1
+            wr = in_cell & ~is_root & par_is_L
+            wl = in_cell & ~is_root & ~par_is_L
+            right = right.at[jnp.where(wr, parent_node, n)].set(node_id, mode="drop")
+            left = left.at[jnp.where(wl, parent_node, n)].set(node_id, mode="drop")
+            node_parent = node_parent.at[
+                jnp.where(in_cell & ~is_root, k + 1, n)
+            ].set(parent_node, mode="drop")
+            root_slot = first_leaf[cells[jnp.clip(k, 0, n - 1)]]
+            right = right.at[jnp.where(is_root, root_slot, n)].set(node_id, mode="drop")
+            node_parent = node_parent.at[jnp.where(is_root, k + 1, n)].set(
+                root_slot, mode="drop"
+            )
+
+        i = jnp.arange(n, dtype=jnp.int32)
+        if n > 1:
+            dl = jnp.where(i > 0, d[jnp.clip(i - 1, 0)], sentinel)
+            dr = jnp.where(i < n - 1, d[jnp.clip(i, 0, max(n - 2, 0))], sentinel)
+        else:
+            dl = jnp.full((n,), sentinel, jnp.uint32)
+            dr = jnp.full((n,), sentinel, jnp.uint32)
+        lone = (dl == sentinel) & (dr == sentinel)
+        lpar_left = dl <= dr
+        lparent = jnp.where(lpar_left, i, i + 1)
+        right = right.at[jnp.where(~lone & lpar_left, lparent, n)].set(~i, mode="drop")
+        left = left.at[jnp.where(~lone & ~lpar_left, lparent, n)].set(~i, mode="drop")
+        right = right.at[jnp.where(lone, i, n)].set(~i, mode="drop")
+        leaf_parent = jnp.where(lone, i, lparent)
+
+        # manual left child: previous interval IN THE SAME ROW (clamp at row start)
+        nonempty = counts > 0
+        row_of_f = f_safe // W
+        prev_in_row = jnp.maximum(f_safe - 1, row_of_f * W)
+        left = left.at[jnp.where(nonempty, f_safe, n)].set(~prev_in_row, mode="drop")
+
+        table = jnp.where(
+            counts == 0, ~cell_first[:-1], jnp.where(overlap == 1, ~f_safe, f_safe)
+        ).astype(jnp.int32)
 
     # Traversal depth per leaf -> per-(row, cell) fallback flags: the same
     # saturating parent chase as the 1-D builder (core.forest._build_cell_
     # trees), so the flags are bit-identical per row — chases never cross a
     # row because every parent edge stays inside its cell.
-    depth = jnp.zeros((n,), jnp.int32)
-    anc = leaf_parent
-    for _ in range(_DEPTH_ITERS):
-        live = anc >= 0
-        depth = depth + live.astype(jnp.int32)
-        anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
-    depth = depth + 1  # the leaf resolution step itself
+    with scope("forest2d.depth_guard"):
+        depth = jnp.zeros((n,), jnp.int32)
+        anc = leaf_parent
+        for _ in range(_DEPTH_ITERS):
+            live = anc >= 0
+            depth = depth + live.astype(jnp.int32)
+            anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
+        depth = depth + 1  # the leaf resolution step itself
 
-    cell_depth = jnp.zeros((n_cells,), jnp.int32).at[cells].max(depth)
-    allowed = jnp.ceil(jnp.log2(jnp.maximum(overlap, 2).astype(jnp.float32)))
-    fallback = (overlap > 1) & (
-        cell_depth > allowed.astype(jnp.int32) + fallback_slack
-    )
+        cell_depth = jnp.zeros((n_cells,), jnp.int32).at[cells].max(depth)
+        allowed = jnp.ceil(jnp.log2(jnp.maximum(overlap, 2).astype(jnp.float32)))
+        fallback = (overlap > 1) & (
+            cell_depth > allowed.astype(jnp.int32) + fallback_slack
+        )
     return RowForest(data, table, left, right, cell_first, R, W, m, fallback)
 
 

@@ -59,7 +59,7 @@ from repro.kernels import ops
 from repro.pool.arena import _pow2_at_least
 from repro.robust.validate import check_policy, sanitize_weights
 from repro.pool.batched import BatchedForest, batched_from_row_forest
-from repro.trace import count, span
+from repro.trace import count, scope, span
 
 
 def _to_device(*arrays) -> list[jax.Array]:
@@ -126,6 +126,18 @@ def _cdf_stack(weights: jax.Array) -> jax.Array:
     ``build_cdf`` — the scan grid is per-row, so every row's bits equal an
     independent ``build_cdf`` call (the class-row semantics contract)."""
     return jax.vmap(build_cdf)(weights)
+
+
+@jax.jit
+def _rows_changed(cdf_rows: jax.Array, slots: jax.Array,
+                  new_cdf: jax.Array) -> jax.Array:
+    """The update's skip key on the device: for each resubmitted row, do
+    its new CDF bits differ from those stored at its slot? One bool per
+    row, so the host reads O(touched rows), never the class stack."""
+    with scope("map2d.skip_key"):
+        old = jax.lax.bitcast_convert_type(cdf_rows[slots], jnp.uint32)
+        new = jax.lax.bitcast_convert_type(new_cdf, jnp.uint32)
+        return (old != new).any(axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
@@ -370,8 +382,13 @@ class Map2DSampler:
         ``skipped_rows`` (the O(dirty rows) structural witness),
         ``cond_launches``, ``marginal_rebuilt``.
 
+        The skip key is compared on the device (scope ``map2d.skip_key``):
+        the host reads one flag per resubmitted row, the marginal's two
+        CDFs and the degenerate flags, never a class's CDF stack.
+
         Runs under the host span ``repro.map2d.update``; its pulls and
-        uploads count in ``host.bytes_out`` / ``host.bytes_in``."""
+        uploads count in ``host.bytes_out`` / ``host.bytes_in``, and the
+        resubmitted rows in ``map2d.rows_rebuilt`` / ``map2d.rows_skipped``."""
         with span("repro.map2d.update"):
             return self._update_map(delta_rows, delta)
 
@@ -399,16 +416,18 @@ class Map2DSampler:
                      marginal_rebuilt=False)
         for wc, rids in sorted(by_class.items()):
             cls = self.classes[wc]
-            slots = np.asarray([self._slot_of[r] for r in rids], np.int64)
+            slots = np.asarray([self._slot_of[r] for r in rids], np.int32)
             stack = np.stack([self._padded_cond(r, wc) for r in rids])
-            new_cdf = _cdf_stack(*_to_device(stack))
+            stack_dev, slots_dev = _to_device(stack, slots)
+            new_cdf = _cdf_stack(stack_dev)
+            changed = _rows_changed(cls.cdf_rows, slots_dev, new_cdf)
             with span("repro.map2d.cdf_pull"):
-                old_rows, new_rows = _to_host(cls.cdf_rows, new_cdf)
-            old_bits = old_rows[slots].view(np.uint32)
-            new_bits = new_rows.view(np.uint32)
-            dirty = np.flatnonzero((old_bits != new_bits).any(axis=1))
+                (changed,) = _to_host(changed)
+            dirty = np.flatnonzero(changed)
             stats["skipped_rows"] += len(rids) - len(dirty)
             cls.skips += len(rids) - len(dirty)
+            count("map2d.rows_skipped", len(rids) - len(dirty))
+            count("map2d.rows_rebuilt", len(dirty))
             if len(dirty) == 0:
                 continue
             # one multi-row launch for the class's dirty rows, padded to a
@@ -418,7 +437,7 @@ class Map2DSampler:
                 [dirty, np.zeros(dpad - len(dirty), np.int64)]
             )
             sel_dev, idx, dirty_dev = _to_device(
-                sel, slots[dirty].astype(np.int32), dirty)
+                sel, slots[dirty], dirty)
             cdf_dirty = new_cdf[sel_dev]
             rf = build_forest_rows(cdf_dirty, m=wc,
                                    fallback_slack=self.fallback_slack)

@@ -126,6 +126,40 @@ def test_update_map_counts_its_pulls_and_uploads():
     stats = sampler.update_map({3: img[3] * 2.0 + 1.0})
     assert stats["rebuilt_rows"] == 1 and stats["marginal_rebuilt"]
     c = trace.counters()
-    class_cdf = 16 * (32 + 1) * 4        # the whole class stack is pulled
-    assert c["host.bytes_out"] >= class_cdf + 2 * 17 * 4
+    # one skip flag for the touched row, the marginal's old and new CDFs,
+    # and the class's and the marginal's degenerate flags: the class's CDF
+    # stack stays on the device
+    assert c["host.bytes_out"] == 1 + 2 * 17 * 4 + 2
     assert c["host.bytes_in"] >= 32 * 4 + 16 * 4
+    assert c["map2d.rows_rebuilt"] == 1 and c["map2d.rows_skipped"] == 0
+
+
+def test_update_map_counts_skipped_rows():
+    img = env_map_2d(16, 32)
+    sampler = Map2DSampler(img)
+    trace.reset_counters()
+    stats = sampler.update_map({3: img[3] * 2.0 + 1.0, 5: img[5], 9: img[9]})
+    assert stats["rebuilt_rows"] == 1 and stats["skipped_rows"] == 2
+    c = trace.counters()
+    assert c["map2d.rows_rebuilt"] == 1 and c["map2d.rows_skipped"] == 2
+
+
+def test_row_build_phases_are_scoped_in_the_compiled_build():
+    from repro.core.forest2d import build_forest_rows
+
+    cdf_rows = jax.vmap(build_cdf)(jnp.stack([_weights(64, s)
+                                              for s in range(4)]))
+    text = build_forest_rows.lower(cdf_rows, 64).compile().as_text()
+    assert {"forest2d.separators", "forest2d.cell_trees",
+            "forest2d.depth_guard"} <= scopes_in_hlo(text)
+
+
+def test_update_skip_key_is_scoped_in_the_compiled_comparison():
+    from repro.spatial.map2d import _rows_changed
+
+    sampler = Map2DSampler(env_map_2d(16, 32))
+    cls = next(iter(sampler.classes.values()))
+    slots = jnp.asarray([1, 4, 7], jnp.int32)
+    text = _rows_changed.lower(cls.cdf_rows, slots,
+                               cls.cdf_rows[:3]).compile().as_text()
+    assert "map2d.skip_key" in scopes_in_hlo(text)
