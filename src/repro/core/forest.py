@@ -55,7 +55,7 @@ INVALID = np.int32(-(2**31))  # never a legal ref; only in untouched slots
 # deep; such cells are flagged for balanced fallback at build time, so 256 is
 # a pure safety guard for fallback-disabled traversal.
 MAX_DEPTH = 256
-_DEPTH_ITERS = 48  # saturating depth count; anything deeper is flagged anyway
+_DEPTH_ITERS = 48  # cap of the saturating depth walk; deeper is flagged anyway
 
 
 class RadixForest(NamedTuple):
@@ -137,6 +137,33 @@ def _separator_distances(data: jax.Array, cells: jax.Array) -> jax.Array:
     sep_raw = bits[:-1] ^ bits[1:]
     crossing = cells[:-1] != cells[1:]
     return jnp.where(crossing, jnp.uint32(DIST_SENTINEL), sep_raw)
+
+
+def _chain_depth(leaf_parent: jax.Array, node_parent: jax.Array):
+    """Saturating traversal depth of every leaf: ``min(chain, _DEPTH_ITERS)
+    + 1``, where ``chain`` counts the live ancestors met from ``leaf_parent``
+    up ``node_parent`` (``-1`` ends a chain), plus the leaf step itself.
+
+    The walk stops when the last chain has ended, after at most
+    ``_DEPTH_ITERS`` trips; a trip leaves an ended chain unchanged, so the
+    depths are those of a fixed ``_DEPTH_ITERS``-trip walk. Under ``vmap``
+    the loop runs until the last row is done, each row keeping its own
+    state. Returns ``(depth, trips)``."""
+
+    def cond(state):
+        anc, _, it = state
+        return jnp.any(anc >= 0) & (it < _DEPTH_ITERS)
+
+    def body(state):
+        anc, depth, it = state
+        with scope("depth_guard.trip"):
+            live = anc >= 0
+            return (jnp.where(live, node_parent[jnp.clip(anc, 0)], anc),
+                    depth + live.astype(jnp.int32), it + 1)
+
+    _, depth, trips = jax.lax.while_loop(
+        cond, body, (leaf_parent, jnp.zeros_like(leaf_parent), jnp.int32(0)))
+    return depth + 1, trips
 
 
 def _build_cell_trees(
@@ -283,14 +310,7 @@ def _build_cell_trees(
     # Traversal depth per leaf -> per-cell fallback flags (paper's degenerate-
     # tree guard: rebuild-as-balanced becomes a per-cell bisection mode).
     with scope("forest.depth_guard"):
-        depth = jnp.zeros((n,), jnp.int32)
-        anc = leaf_parent
-        for _ in range(_DEPTH_ITERS):
-            live = anc >= 0
-            depth = depth + live.astype(jnp.int32)
-            anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
-        depth = depth + 1  # the leaf resolution step itself
-
+        depth, _ = _chain_depth(leaf_parent, node_parent)
         cell_depth = jnp.zeros((m_local,), jnp.int32).at[loc_safe].max(
             depth, mode="drop"
         )

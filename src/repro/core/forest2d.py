@@ -44,7 +44,7 @@ import numpy as np
 
 from .bits import DIST_SENTINEL
 from .cdf import lower_bounds
-from .forest import _DEPTH_ITERS, INVALID, MAX_DEPTH, _nearest_greater
+from .forest import INVALID, MAX_DEPTH, _chain_depth, _nearest_greater
 from .bits import float_to_bits
 from ..trace import scope
 
@@ -153,19 +153,12 @@ def build_forest_rows(
             counts == 0, ~cell_first[:-1], jnp.where(overlap == 1, ~f_safe, f_safe)
         ).astype(jnp.int32)
 
-    # Traversal depth per leaf -> per-(row, cell) fallback flags: the same
-    # saturating parent chase as the 1-D builder (core.forest._build_cell_
-    # trees), so the flags are bit-identical per row — chases never cross a
-    # row because every parent edge stays inside its cell.
+    # Traversal depth per leaf -> per-(row, cell) fallback flags: the 1-D
+    # builder's walk (core.forest._chain_depth), so the flags are
+    # bit-identical per row — chases never cross a row because every parent
+    # edge stays inside its cell.
     with scope("forest2d.depth_guard"):
-        depth = jnp.zeros((n,), jnp.int32)
-        anc = leaf_parent
-        for _ in range(_DEPTH_ITERS):
-            live = anc >= 0
-            depth = depth + live.astype(jnp.int32)
-            anc = jnp.where(live, node_parent[jnp.clip(anc, 0)], anc)
-        depth = depth + 1  # the leaf resolution step itself
-
+        depth, _ = _chain_depth(leaf_parent, node_parent)
         cell_depth = jnp.zeros((n_cells,), jnp.int32).at[cells].max(depth)
         allowed = jnp.ceil(jnp.log2(jnp.maximum(overlap, 2).astype(jnp.float32)))
         fallback = (overlap > 1) & (

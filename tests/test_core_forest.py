@@ -1,7 +1,10 @@
 """Property + unit tests for the radix-tree-forest core (paper Secs. 2-3)."""
+import contextlib
+
 import hypothesis
 import hypothesis.strategies as st
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -252,3 +255,166 @@ def test_batch_cost_is_lane_max():
     groups = v[: 1024 // 32 * 32].reshape(-1, 32)
     assert np.all(groups.max(axis=1) >= groups.mean(axis=1))
     assert v.max() <= 64  # bounded by MAX_DEPTH guard for real CDFs
+
+
+# ------------------------------------------------ the depth guard's walk
+
+
+def _fixed_chain_depth(leaf_parent, node_parent):
+    """The depth guard's walk before it stopped at the last chain's end: a
+    fixed ``_DEPTH_ITERS``-step chase (the oracle)."""
+    from repro.core.forest import _DEPTH_ITERS
+
+    def step(_, state):
+        anc, depth = state
+        live = anc >= 0
+        return (jnp.where(live, node_parent[jnp.clip(anc, 0)], anc),
+                depth + live.astype(jnp.int32))
+
+    _, depth = jax.lax.fori_loop(0, _DEPTH_ITERS, step,
+                                 (leaf_parent, jnp.zeros_like(leaf_parent)))
+    return depth + 1, jnp.int32(_DEPTH_ITERS)
+
+
+@contextlib.contextmanager
+def _depth_walk(walk):
+    """Run the builders with ``walk`` in place of ``_chain_depth``. Builds
+    inside trace anew (``_build_with`` jits a fresh function each time, and
+    the sharded builder's program cache is dropped on entry and exit)."""
+    from repro.core import forest as F, forest2d as F2
+    from repro.dist import forest as DF
+
+    saved = F._chain_depth
+    F._chain_depth = F2._chain_depth = walk
+    DF._windowed_builder.cache_clear()
+    try:
+        yield
+    finally:
+        F._chain_depth = F2._chain_depth = saved
+        DF._windowed_builder.cache_clear()
+
+
+def _checked_walk(seen: list):
+    """The builders' own walk, which also hands each call's ``(depth equals
+    the fixed walk's, trips)`` to ``seen`` from inside the program, under
+    ``jit``, ``vmap`` and ``shard_map`` alike (one call per row or shard)."""
+    from repro.core.forest import _chain_depth
+
+    def walk(leaf_parent, node_parent):
+        depth, trips = _chain_depth(leaf_parent, node_parent)
+        want, _ = _fixed_chain_depth(leaf_parent, node_parent)
+        jax.debug.callback(lambda same, t: seen.append((bool(same), int(t))),
+                           jnp.all(depth == want), trips)
+        return depth, trips
+
+    return walk
+
+
+def _chain_weights(case):
+    """(weights, guide cells) of one forest for the depth-walk tests."""
+    if case == "zipf":                       # Zipf^0.75 at m = n
+        n = 4096
+        return (1.0 / np.arange(1, n + 1)) ** 0.75, n
+    if case == "spike":                      # 151 ties at 0, 149 at the top
+        w = np.zeros(300)
+        w[150] = 1.2
+        return w, 16
+    if case == "interior_ties":              # 20 ties inside one cell
+        w = np.random.default_rng(7).random(64) + 0.1
+        w[20:40] = 0.0
+        return w, 4
+    if case == "tied_run":                   # 298 ties: saturates at 48
+        w = np.zeros(300)
+        w[0], w[299] = 1.2, 0.8
+        return w, 1
+    if case == "dyadic_chain":               # 24 levels in one cell
+        k = 24
+        return [2.0 ** -(i + 1) for i in range(k)] + [2.0 ** -k], 1
+    if case == "n1":
+        return [3.0], 8
+    if case == "n2":
+        return [1.0, 2.0], 4
+    assert case == "all_leaves"              # one interval per guide cell
+    return np.ones(1024), 1024
+
+
+WEIGHT_FAMILIES = ("zipf", "spike", "interior_ties", "tied_run",
+                   "dyadic_chain", "n1", "n2")
+CHAIN_CASES = WEIGHT_FAMILIES + ("all_leaves",)
+
+
+def _longest_chain(f: dict) -> int:
+    """Longest leaf-to-root chain of a forest from ``depth_stats``'s numpy
+    walk down every cell's tree: the nodes met above the deepest leaf. Every
+    leaf hangs at least under its own cell's root slot, so it is at least 1."""
+    from repro.core.forest import RadixForest
+
+    fields = {k: f[k] for k in RadixForest._fields}
+    return max(depth_stats(RadixForest(**fields))["max_depth"] - 1, 1)
+
+
+def _build_with(builder, w, m):
+    """One build of ``w`` (and, for the pool, its mirror image) through
+    ``builder``, traced anew, as numpy arrays keyed by field."""
+    from repro.pool.batched import build_forest_batched_from_cdf
+
+    cdf = build_cdf(jnp.asarray(np.asarray(w, np.float32)))
+    if builder == "forest":
+        out = jax.jit(lambda c: build_forest_from_cdf.__wrapped__(c, m))(cdf)
+    elif builder == "pool":
+        cdfs = jnp.stack([cdf, build_cdf(jnp.asarray(
+            np.asarray(w, np.float32)[::-1]))])
+        out = jax.jit(lambda c: build_forest_batched_from_cdf.__wrapped__(
+            c, m))(cdfs)
+    else:
+        from jax.sharding import Mesh
+
+        from repro.dist import forest as DF
+
+        D = max(d for d in (1, 2, 4, 8)
+                if d <= jax.device_count() and m % d == 0)
+        mesh = Mesh(np.array(jax.devices()[:D]), ("data",))
+        out = DF.gather_forest(DF.build_forest_from_cdf_sharded(
+            cdf, m, mesh=mesh))
+    jax.effects_barrier()
+    return {k: np.asarray(v) for k, v in out._asdict().items()}
+
+
+@pytest.mark.parametrize("builder", ["forest", "pool", "sharded"])
+@pytest.mark.parametrize("case", WEIGHT_FAMILIES)
+def test_depth_walk_matches_fixed_walk(case, builder):
+    """The depth guard's walk stops when the last chain ends, yet every
+    leaf's depth equals the fixed 48-step walk's, and the whole forest and
+    its fallback flags are those built with the fixed walk: the 1-D builder,
+    the vmapped pool builder and the sharded builder at this process's
+    device count."""
+    w, m = _chain_weights(case)
+    seen: list = []
+    with _depth_walk(_checked_walk(seen)):
+        got = _build_with(builder, w, m)
+    with _depth_walk(_fixed_chain_depth):
+        want = _build_with(builder, w, m)
+    assert seen and all(same for same, _ in seen), seen
+    assert all(1 <= t <= 48 for _, t in seen), seen
+    for key in want:
+        assert np.array_equal(got[key], want[key]), key
+    if case in ("spike", "tied_run", "dyadic_chain"):
+        assert got["fallback"].any()
+
+
+@pytest.mark.parametrize("case", CHAIN_CASES)
+def test_depth_walk_trip_count(case):
+    """The walk takes as many trips as the forest's longest leaf-to-root
+    chain, capped at 48: one for a forest of lone leaves (no internal node),
+    48 for a tied run deeper than that."""
+    w, m = _chain_weights(case)
+    seen: list = []
+    with _depth_walk(_checked_walk(seen)):
+        f = _build_with("forest", w, m)
+    (same, trips), = seen
+    assert same
+    assert trips == min(_longest_chain(f), 48)
+    expect = {"tied_run": 48, "spike": 48, "dyadic_chain": 25,
+              "all_leaves": 1, "n1": 1, "n2": 1}
+    if case in expect:
+        assert trips == expect[case]

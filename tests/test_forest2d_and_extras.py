@@ -176,6 +176,88 @@ def test_forest2d_depth_bound():
         assert o_max > bound  # the gate actually distinguishes log from linear
 
 
+def _fixed_chain_depth(leaf_parent, node_parent):
+    """The row builder's depth walk before it stopped at the last chain's
+    end: a fixed ``_DEPTH_ITERS``-step chase (the oracle)."""
+    from repro.core.forest import _DEPTH_ITERS
+
+    def step(_, state):
+        anc, depth = state
+        live = anc >= 0
+        return (jnp.where(live, node_parent[jnp.clip(anc, 0)], anc),
+                depth + live.astype(jnp.int32))
+
+    _, depth = jax.lax.fori_loop(0, _DEPTH_ITERS, step,
+                                 (leaf_parent, jnp.zeros_like(leaf_parent)))
+    return depth + 1, jnp.int32(_DEPTH_ITERS)
+
+
+def _row_stack(case):
+    """(3, n+1) CDFs and guide cells: one weight family, its mirror image,
+    and a row of uniform weights, so the rows' chains differ in length."""
+    if case == "zipf":
+        w, m = (1.0 / np.arange(1, 4097)) ** 0.75, 4096
+    elif case == "spike":
+        w, m = np.zeros(300), 16
+        w[150] = 1.2
+    elif case == "interior_ties":
+        w, m = np.random.default_rng(7).random(64) + 0.1, 4
+        w[20:40] = 0.0
+    elif case == "tied_run":
+        w, m = np.zeros(300), 1
+        w[0], w[299] = 1.2, 0.8
+    elif case == "dyadic_chain":
+        w, m = np.array([2.0 ** -(i + 1) for i in range(24)] + [2.0 ** -24]), 1
+    else:
+        w, m = {"n1": ([3.0], 8), "n2": ([1.0, 2.0], 4),
+                "all_leaves": (np.ones(1024), 1024)}[case]
+    w = np.asarray(w, np.float32)
+    flat = np.random.default_rng(1).random(w.size) + 0.5
+    cdfs = np.stack([np_build_cdf(normalize_weights(r))
+                     for r in (w, w[::-1], flat)])
+    return jnp.asarray(cdfs, jnp.float32), m
+
+
+@pytest.mark.parametrize("case", ["zipf", "spike", "interior_ties", "tied_run",
+                                  "dyadic_chain", "n1", "n2", "all_leaves"])
+def test_row_depth_walk_matches_fixed_walk(case, monkeypatch):
+    """``build_forest_rows`` walks every row's chains in one loop that stops
+    when the longest chain of any row ends: each leaf's depth equals the
+    fixed 48-step walk's, the flat forest and its fallback flags are those
+    built with the fixed walk, and the trips are the longest leaf-to-root
+    chain of the flat forest (``depth_stats``'s numpy walk), capped at 48."""
+    from repro.core import depth_stats, forest2d as F2
+    from repro.core.forest import RadixForest, _chain_depth
+
+    cdfs, m = _row_stack(case)
+    seen = []
+
+    def checked(leaf_parent, node_parent):
+        depth, trips = _chain_depth(leaf_parent, node_parent)
+        want, _ = _fixed_chain_depth(leaf_parent, node_parent)
+        jax.debug.callback(lambda same, t: seen.append((bool(same), int(t))),
+                           jnp.all(depth == want), trips)
+        return depth, trips
+
+    def build(walk):   # a fresh function, so the walk in place is traced
+        monkeypatch.setattr(F2, "_chain_depth", walk)
+        f = jax.jit(lambda c: build_forest_rows.__wrapped__(c, m))(cdfs)
+        jax.effects_barrier()
+        return f
+
+    got, want = build(checked), build(_fixed_chain_depth)
+    for key in ("data", "table", "left", "right", "cell_first", "fallback"):
+        assert np.array_equal(np.asarray(getattr(got, key)),
+                              np.asarray(getattr(want, key))), key
+    (same, trips), = seen
+    assert same
+    stats = depth_stats(RadixForest(got.data, got.table, got.left, got.right,
+                                    got.cell_first, got.fallback))
+    assert trips == min(max(stats["max_depth"] - 1, 1), 48)
+    if case in ("spike", "tied_run"):
+        assert trips == 48
+
+
 # ---------------------------------------------------------------- LDS props
 
 
