@@ -44,7 +44,8 @@ import numpy as np
 
 from .bits import DIST_SENTINEL
 from .cdf import lower_bounds
-from .forest import INVALID, MAX_DEPTH, _chain_depth, _nearest_greater
+from .forest import INVALID, _chain_depth, _nearest_greater
+from .sample import bisect, descend
 from .bits import float_to_bits
 from ..trace import scope
 
@@ -58,7 +59,7 @@ class RowForest(NamedTuple):
     rows: int
     width: int
     m: int
-    fallback: jax.Array | None = None  # (R*m,) bool degenerate (row, cell)
+    fallback: jax.Array    # (R*m,) bool degenerate (row, cell)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "fallback_slack"))
@@ -170,25 +171,16 @@ def build_forest_rows(
 @functools.partial(jax.jit, static_argnames=())
 def sample_forest_rows(f: RowForest, row: jax.Array, xi: jax.Array) -> jax.Array:
     """Sample column index within each lane's row: (rows (B,), xi (B,)) ->
-    column ids (B,). Batched Algorithm 2 over the flat forest."""
+    column ids (B,). Batched Algorithm 2 over the flat forest, with the 1-D
+    sampler's degenerate-cell bisection, kept inside the lane's row."""
     m, W = f.m, f.width
-    n = f.left.shape[0]
     g = row * m + jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
     j = f.table[g]
-
-    def cond(state):
-        j, it = state
-        return jnp.any(j >= 0) & (it < MAX_DEPTH)
-
-    def body(state):
-        j, it = state
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < f.data[jj]
-        nxt = jnp.where(go_left, f.left[jj], f.right[jj])
-        return jnp.where(j >= 0, nxt, j), it + 1
-
-    j, _ = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
-    flat = ~j
+    flagged = f.fallback[g] & (j >= 0)
+    lo = f.cell_first[g]
+    hi = jnp.where(flagged, jnp.minimum(f.cell_first[g + 1], row * W + W - 1), lo)
+    j = jnp.where(flagged, ~bisect(f.data, xi, lo, hi)[0], j)
+    flat, _ = descend(f.data, f.left, f.right, xi, j)
     return flat - row * W   # column within the row
 
 

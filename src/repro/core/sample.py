@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..trace import scope
 from .forest import MAX_DEPTH, RadixForest
 
 
@@ -28,17 +29,77 @@ def sample_binary(cdf: jax.Array, xi: jax.Array) -> jax.Array:
     return jnp.clip(i, 0, cdf.shape[0] - 2)
 
 
-def _bisect(cdf: jax.Array, xi: jax.Array, lo: jax.Array, hi: jax.Array, steps: int):
-    """Find i in [lo, hi] with cdf[i] <= xi < cdf[i+1]; fixed-trip bisection."""
+# Trip cap of the degenerate-cell bisection: enough to halve any int32 span.
+MAX_BISECT = 32
 
-    def body(_, state):
-        lo, hi = state
-        mid = (lo + hi + 1) >> 1
-        ge = xi >= cdf[mid]
-        return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
 
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    return lo
+def _gather(a: jax.Array, i: jax.Array) -> jax.Array:
+    return a[i]
+
+
+def bisect_trip(cdf, xi, lo, hi, *, at=_gather):
+    """One trip of balanced index bisection towards the i in [lo, hi] with
+    ``cdf[i] <= xi < cdf[i+1]``; a lane with ``lo >= hi`` keeps its ``lo``.
+    ``at(table, i)`` gathers a table at lane indices."""
+    mid = (lo + hi + 1) >> 1
+    ge = xi >= at(cdf, mid)
+    return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
+
+
+def bisect(cdf, xi, lo, hi, *, at=_gather):
+    """Balanced index bisection, the paper's logarithmic-worst-case guard for
+    degenerate cells: ``(i, trips)``. The loop stops when every lane is
+    resolved (``lo >= hi``), after at most :data:`MAX_BISECT` trips, with the
+    answer of the fixed-trip loop; a caller gives an unflagged lane
+    ``hi = lo`` so that it costs no trip."""
+
+    def cond(state):
+        lo, hi, it = state
+        return jnp.any(lo < hi) & (it < MAX_BISECT)
+
+    def body(state):
+        lo, hi, it = state
+        with scope("descent.bisect_trip"):
+            return (*bisect_trip(cdf, xi, lo, hi, at=at), it + 1)
+
+    lo, _, trips = jax.lax.while_loop(cond, body, (lo, hi, jnp.int32(0)))
+    return lo, trips
+
+
+def descent_trip(cdf, left, right, xi, j, *, at=_gather, node=None):
+    """One trip of Algorithm 2's descent: a lane at node ``j >= 0`` moves to
+    its left child iff ``xi < cdf[j]``, else to its right; a lane at a leaf
+    (``j < 0``) stays. ``node(j)`` gives the index of node ``j`` in ``cdf``
+    and in ``left``/``right`` (a shard holds its children in a window);
+    by default both are ``j`` clipped into the child tables."""
+    if node is None:
+        key = child = jnp.clip(j, 0, left.shape[-1] - 1)
+    else:
+        key, child = node(j)
+    go_left = xi < at(cdf, key)
+    nxt = jnp.where(go_left, at(left, child), at(right, child))
+    return jnp.where(j >= 0, nxt, j)
+
+
+def descend(cdf, left, right, xi, j, *, at=_gather, node=None):
+    """Algorithm 2's descent from node refs ``j`` (leaf refs ``~i`` are
+    resolved): ``(idx, trips)``. The loop stops when its deepest lane
+    reaches a leaf, after at most ``MAX_DEPTH`` trips; a lane at a leaf is
+    left unchanged by a trip, so the answer is that of the fixed-trip loop.
+    Every XLA sampler of the repo runs it, each with its own start nodes and
+    gathers (:func:`descent_trip`)."""
+
+    def cond(state):
+        j, it = state
+        return jnp.any(j >= 0) & (it < MAX_DEPTH)
+
+    def body(state):
+        j, it = state
+        with scope("descent.trip"):
+            return descent_trip(cdf, left, right, xi, j, at=at, node=node), it + 1
+
+    j, trips = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
+    return ~j, trips
 
 
 def sample_cutpoint_binary(
@@ -49,9 +110,7 @@ def sample_cutpoint_binary(
     ((m+1,), conservative last = first of next cell)."""
     m = cell_first.shape[0] - 1
     g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
-    lo = cell_first[g]
-    hi = cell_first[g + 1]
-    return _bisect(cdf, xi, lo, hi, 32)
+    return bisect(cdf, xi, cell_first[g], cell_first[g + 1])[0]
 
 
 def sample_cutpoint_linear(
@@ -69,12 +128,9 @@ def sample_cutpoint_linear(
     return jax.lax.fori_loop(0, max_scan, body, i)
 
 
-@functools.partial(jax.jit, static_argnames=("use_fallback", "unroll"))
+@functools.partial(jax.jit, static_argnames=("use_fallback",))
 def sample_forest(
-    forest: RadixForest,
-    xi: jax.Array,
-    use_fallback: bool = True,
-    unroll: int = 1,
+    forest: RadixForest, xi: jax.Array, use_fallback: bool = True
 ) -> jax.Array:
     """Algorithm 2: guide-table lookup, then radix-tree descent.
 
@@ -83,57 +139,24 @@ def sample_forest(
     cells (``forest.fallback``) use balanced index bisection instead — the
     paper's logarithmic-worst-case guard.
     """
-    cdf, table, left, right = forest.cdf, forest.table, forest.left, forest.right
-    n = forest.n
     m = forest.m
     g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
-    j = table[g]
-
-    if use_fallback:
-        fb = forest.fallback[g] & (j >= 0)
+    j = forest.table[g]
+    if use_fallback:  # pre-resolve fallback lanes
+        flagged = forest.fallback[g] & (j >= 0)
         lo = forest.cell_first[g]
-        hi = forest.cell_first[g + 1]
-        bal = _bisect(cdf, xi, lo, hi, 32)
-        j = jnp.where(fb, ~bal, j)  # pre-resolve fallback lanes
-
-    def cond(state):
-        j, it = state
-        return jnp.any(j >= 0) & (it < MAX_DEPTH)
-
-    def body(state):
-        j, it = state
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < cdf[jj]
-        nxt = jnp.where(go_left, left[jj], right[jj])
-        return jnp.where(j >= 0, nxt, j), it + 1
-
-    j, _ = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
-    return ~j
+        hi = jnp.where(flagged, forest.cell_first[g + 1], lo)
+        j = jnp.where(flagged, ~bisect(forest.cdf, xi, lo, hi)[0], j)
+    return descend(forest.cdf, forest.left, forest.right, xi, j)[0]
 
 
 def sample_forest_with_stats(forest: RadixForest, xi: jax.Array):
-    """As :func:`sample_forest` but also returns per-lane node-visit counts
-    (loads beyond the guide-table load) — the Table-1 instrumentation."""
-    cdf, table, left, right = forest.cdf, forest.table, forest.left, forest.right
-    n, m = forest.n, forest.m
+    """As :func:`sample_forest` without the fallback, but also returns
+    per-lane node-visit counts (loads beyond the guide-table load) — the
+    Table-1 instrumentation. Each lane runs :func:`descend` alone (a vmap),
+    so its trip count is its visit count."""
+    m = forest.m
     g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
-    j = table[g]
-
-    def cond(state):
-        j, _c, it = state
-        return jnp.any(j >= 0) & (it < MAX_DEPTH)
-
-    def body(state):
-        j, c, it = state
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < cdf[jj]
-        nxt = jnp.where(go_left, left[jj], right[jj])
-        active = j >= 0
-        return (
-            jnp.where(active, nxt, j),
-            c + active.astype(jnp.int32),
-            it + 1,
-        )
-
-    j, c, _ = jax.lax.while_loop(cond, body, (j, jnp.zeros_like(g), jnp.int32(0)))
-    return ~j, c
+    return jax.vmap(
+        lambda x, j: descend(forest.cdf, forest.left, forest.right, x, j)
+    )(xi, forest.table[g])

@@ -92,7 +92,7 @@ from repro.core.forest import (
     _build_cell_trees,
     _cells,
 )
-from repro.core.sample import MAX_DEPTH, _bisect
+from repro.core.sample import bisect, descend
 from repro.kernels import ops
 
 # Window capacities are rounded up to this granule: coarse enough that small
@@ -865,6 +865,12 @@ def sample_sharded(
     return (out, stats) if with_stats else out
 
 
+def _window(n: int, cap: int, start: jax.Array):
+    """A shard's node indices for ``core.sample.descend``: the CDF is
+    indexed globally, the children by slot in the shard's window."""
+    return lambda j: (jnp.clip(j, 0, n - 1), jnp.clip(j - start, 0, cap - 1))
+
+
 @functools.lru_cache(maxsize=128)
 def _routed_sampler(
     mesh: Mesh, axis: str, m: int, n: int, cap: int, use_fallback: bool,
@@ -912,25 +918,15 @@ def _routed_sampler(
         j = jnp.where(live, table[rg], jnp.int32(-1))
         if use_fallback:
             flagged = live & fb[rg] & (j >= 0)
-            bal = _bisect(cdf, rx, cell_first[rg], cell_first[rg + 1], 32)
-            j = jnp.where(flagged, ~bal, j)
-
-        def cond(state):
-            j, it = state
-            return jnp.any(j >= 0) & (it < MAX_DEPTH)
-
-        def body(state):
-            j, it = state
-            jw = jnp.clip(j - start, 0, cap - 1)     # window slot of node j
-            go_left = rx < cdf[jnp.clip(j, 0, n - 1)]
-            nxt = jnp.where(go_left, left_l[jw], right_l[jw])
-            return jnp.where(j >= 0, nxt, j), it + 1
-
-        j, _ = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
+            lo = cell_first[rg]
+            hi = jnp.where(flagged, cell_first[rg + 1], lo)
+            j = jnp.where(flagged, ~bisect(cdf, rx, lo, hi)[0], j)
+        got, _ = descend(cdf, left_l, right_l, rx, j,
+                         node=_window(n, cap, start))
 
         # Route interval ids back: the same all_to_all inverts the exchange,
         # then the inverse sort permutation restores batch order.
-        back = jax.lax.all_to_all((~j).reshape(D, K), axis, 0, 0, tiled=True)
+        back = jax.lax.all_to_all(got.reshape(D, K), axis, 0, 0, tiled=True)
         return jnp.zeros((lanes,), jnp.int32).at[order].set(back[so, rank])
 
     return jax.jit(shard_map(
@@ -956,22 +952,12 @@ def _sampler(mesh: Mesh, axis: str, m: int, n: int, cap: int, use_fallback: bool
 
         if use_fallback:
             flagged = owned & fb[g] & (j >= 0)
-            bal = _bisect(cdf, xi, cell_first[g], cell_first[g + 1], 32)
-            j = jnp.where(flagged, ~bal, j)
-
-        def cond(state):
-            j, it = state
-            return jnp.any(j >= 0) & (it < MAX_DEPTH)
-
-        def body(state):
-            j, it = state
-            jw = jnp.clip(j - start, 0, cap - 1)     # window slot of node j
-            go_left = xi < cdf[jnp.clip(j, 0, n - 1)]
-            nxt = jnp.where(go_left, left_l[jw], right_l[jw])
-            return jnp.where(j >= 0, nxt, j), it + 1
-
-        j, _ = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
-        return jax.lax.psum(jnp.where(owned, ~j, 0), axis)
+            lo = cell_first[g]
+            hi = jnp.where(flagged, cell_first[g + 1], lo)
+            j = jnp.where(flagged, ~bisect(cdf, xi, lo, hi)[0], j)
+        got, _ = descend(cdf, left_l, right_l, xi, j,
+                         node=_window(n, cap, start))
+        return jax.lax.psum(jnp.where(owned, got, 0), axis)
 
     return jax.jit(shard_map(
         shard_fn, mesh=mesh,

@@ -12,9 +12,10 @@ is tighter on TPU than on the paper's GPUs.
 Gathers (``jnp.take`` from VMEM) are the honest cost: one per lane per level.
 Depth is bounded (<= ~34 for distinct float32 keys; build flags tied chains
 into fallback cells which ops.py pre-resolves), so `depth` is static: every
-kernel runs its `depth` trips. The XLA formulation in :mod:`repro.kernels.ref`
-(what ``ops`` runs for the ops in ``ops.XLA_ONLY``) instead stops at the
-deepest lane, at most `depth` trips, with the same answers.
+kernel runs its `depth` trips of ``core.sample.descent_trip``, the body of
+the XLA descent ``core.sample.descend`` (what ``ops`` runs for the ops in
+``ops.XLA_ONLY``), which instead stops at the deepest lane with the same
+answers.
 
 :func:`forest_sample_batched` is the multi-distribution twin (the
 ``repro.pool`` serving workload): B stacked forests resident at once, each
@@ -48,6 +49,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.lds import qmc_point
+from repro.core.sample import MAX_BISECT, bisect_trip, descent_trip
+
+
+def _take(a, i):
+    return jnp.take(a, i, axis=0)
 
 
 def _forest_kernel(
@@ -58,7 +64,6 @@ def _forest_kernel(
     else:
         xi_ref, o_ref = rest
     xi = xi_ref[...]
-    n = left_ref.shape[0]
     g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
     j = jnp.take(table_ref[...], g, axis=0)
     cdf = cdf_ref[...]
@@ -69,22 +74,16 @@ def _forest_kernel(
         # Pre-resolve lanes in degenerate cells by balanced index bisection
         # (the paper's logarithmic-worst-case guard) — without this, tied
         # zero-width chains exceed any fixed `depth` and the descent below
-        # returns an unresolved internal node. The SAME _bisect as
-        # core.sample.sample_forest, so elementwise agreement is structural.
-        from repro.core.sample import _bisect
-
+        # returns an unresolved internal node.
         flagged = (jnp.take(fb_ref[...], g, axis=0) > 0) & (j >= 0)
         cf = cf_ref[...]
-        bal = _bisect(cdf, xi, jnp.take(cf, g, axis=0), jnp.take(cf, g + 1, axis=0), 32)
-        j = jnp.where(flagged, ~bal, j)
+        lo, _ = jax.lax.fori_loop(
+            0, MAX_BISECT, lambda _, s: bisect_trip(cdf, xi, *s, at=_take),
+            (jnp.take(cf, g, axis=0), jnp.take(cf, g + 1, axis=0)))
+        j = jnp.where(flagged, ~lo, j)
 
-    def body(_, j):
-        jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < jnp.take(cdf, jj, axis=0)
-        nxt = jnp.where(go_left, jnp.take(left, jj, axis=0), jnp.take(right, jj, axis=0))
-        return jnp.where(j >= 0, nxt, j)
-
-    j = jax.lax.fori_loop(0, depth, body, j)
+    j = jax.lax.fori_loop(
+        0, depth, lambda _, j: descent_trip(cdf, left, right, xi, j, at=_take), j)
     o_ref[...] = ~j
 
 
@@ -142,25 +141,21 @@ def _forest_batched_kernel(
         cf = cf_ref[...].reshape(-1)    # (B*(m+1),)
         lo = jnp.take(cf, did * (m + 1) + g)
         hi = jnp.take(cf, did * (m + 1) + g + 1)
-
-        def bisect_body(_, state):
-            lo, hi = state
-            mid = (lo + hi + 1) >> 1
-            ge = xi >= jnp.take(cdf, cbase + mid)
-            return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
-
-        lo, _ = jax.lax.fori_loop(0, 32, bisect_body, (lo, hi))
+        lo, _ = jax.lax.fori_loop(
+            0, MAX_BISECT,
+            lambda _, s: bisect_trip(
+                cdf, xi, *s, at=lambda a, i: jnp.take(a, cbase + i)),
+            (lo, hi))
         j = jnp.where(flagged, ~lo, j)
 
-    def body(_, j):
+    def node(j):  # flat offsets into the lane's row of the key and child tables
         jj = jnp.clip(j, 0, n - 1)
-        go_left = xi < jnp.take(cdf, cbase + jj)
-        nxt = jnp.where(
-            go_left, jnp.take(left, nbase + jj), jnp.take(right, nbase + jj)
-        )
-        return jnp.where(j >= 0, nxt, j)
+        return cbase + jj, nbase + jj
 
-    j = jax.lax.fori_loop(0, depth, body, j)
+    j = jax.lax.fori_loop(
+        0, depth,
+        lambda _, j: descent_trip(cdf, left, right, xi, j, at=jnp.take, node=node),
+        j)
     o_ref[...] = ~j
 
 

@@ -150,8 +150,9 @@ def forest_sample(forest: RadixForest, xi: jax.Array,
     pre-resolve by bisection instead of running past the trip cap.
     Well-conditioned forests (no flagged cell — the common case) skip the
     side tables and the pre-resolution entirely. The XLA formulation stops
-    its bisection (at most 32 trips) and its descent (at most 64) when the
-    deepest lane is done; the Pallas kernel runs its static ``depth`` trips."""
+    its bisection and its descent (``core.sample.bisect``, ``.descend``)
+    when the deepest lane is done; the Pallas kernel runs its static
+    ``depth`` trips."""
     cf, fb = _degenerate_tables(forest, degenerate)
     interpret = _kernel("forest_sample", use_pallas)
     if interpret is None:

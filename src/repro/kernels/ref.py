@@ -1,9 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
 
 The descent oracles are also the XLA formulation that ``kernels.ops`` runs
-for the ops in ``ops.XLA_ONLY``. Their loops stop at the deepest lane (the
-descent at most ``depth`` trips, the degenerate-cell bisection at most 32),
-where the Pallas kernels run their static ``depth`` trips.
+for the ops in ``ops.XLA_ONLY``: the guide lookups of the two table layouts
+in front of ``core.sample``'s one descent and bisection.
 """
 from __future__ import annotations
 
@@ -11,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bits import DIST_SENTINEL
-from repro.trace import scope
+from repro.core.sample import bisect, descend
 
 
 def ref_cdf_scan(x: jax.Array, softmax: bool = True) -> jax.Array:
@@ -38,31 +37,8 @@ def ref_sample_rows(cdf_rows: jax.Array, xi: jax.Array) -> jax.Array:
     return jax.vmap(one)(cdf_rows, xi)
 
 
-def _bisect(at, cdf, xi, lo, hi, steps: int = 32):
-    """Balanced index bisection for lanes in degenerate cells: find i in
-    [lo, hi] with cdf[i] <= xi < cdf[i+1]. A lane with ``lo >= hi`` is
-    resolved and its ``lo`` moves no more, so the loop stops when every
-    lane is resolved, or after ``steps`` trips, and returns ``lo`` as the
-    fixed-trip bisection of ``core.sample`` does, beside the trips taken."""
-
-    def cond(state):
-        lo, hi, it = state
-        return jnp.any(lo < hi) & (it < steps)
-
-    def body(state):
-        lo, hi, it = state
-        with scope("descent.bisect_trip"):
-            mid = (lo + hi + 1) >> 1
-            ge = xi >= at(cdf, mid)
-            return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1), it + 1
-
-    lo, _, trips = jax.lax.while_loop(cond, body, (lo, hi, jnp.int32(0)))
-    return lo, trips
-
-
 def ref_forest_descent(
-    cdf, table, left, right, xi, cell_first=None, fallback=None,
-    depth: int = 64, dist_id=None,
+    cdf, table, left, right, xi, cell_first=None, fallback=None, dist_id=None
 ):
     """Algorithm 2 as XLA ops, for the descent ops of both table layouts:
     ``(idx, trips, bisect_trips)``.
@@ -71,12 +47,10 @@ def ref_forest_descent(
     B, and lane q descends row ``dist_id[q]`` with 2-D gathers (sentinel
     lanes, ``dist_id < 0``, resolve to 0 without descending). With the
     ``cell_first``/``fallback`` side tables, lanes in flagged cells first
-    resolve by bisection. The descent stops when its deepest lane reaches a
-    leaf, after at most ``depth`` trips; a lane at a leaf is left unchanged
-    by a trip, so the answer is that of a fixed ``depth``-trip loop. Both
-    loops return their trip counts, which the ops drop."""
+    resolve by bisection. The loops are ``core.sample.bisect`` and
+    ``core.sample.descend``; both return their trip counts, which the ops
+    drop."""
     m = table.shape[-1]
-    n = left.shape[-1]
     g = jnp.clip(jnp.floor(xi * jnp.float32(m)).astype(jnp.int32), 0, m - 1)
     if dist_id is None:
         def at(a, i):
@@ -95,39 +69,25 @@ def ref_forest_descent(
         flagged = at(fallback, g) & (j >= 0)
         lo = at(cell_first, g)
         hi = jnp.where(flagged, at(cell_first, g + 1), lo)
-        lo, bisect_trips = _bisect(at, cdf, xi, lo, hi)
+        lo, bisect_trips = bisect(cdf, xi, lo, hi, at=at)
         j = jnp.where(flagged, ~lo, j)
-
-    def cond(state):
-        j, it = state
-        return jnp.any(j >= 0) & (it < depth)
-
-    def body(state):
-        j, it = state
-        with scope("descent.trip"):
-            jj = jnp.clip(j, 0, n - 1)
-            go_left = xi < at(cdf, jj)
-            nxt = jnp.where(go_left, at(left, jj), at(right, jj))
-            return jnp.where(j >= 0, nxt, j), it + 1
-
-    j, trips = jax.lax.while_loop(cond, body, (j, jnp.int32(0)))
-    return ~j, trips, bisect_trips
+    idx, trips = descend(cdf, left, right, xi, j, at=at)
+    return idx, trips, bisect_trips
 
 
 def ref_forest_sample(
-    cdf, table, left, right, xi, cell_first=None, fallback=None, depth: int = 64
+    cdf, table, left, right, xi, cell_first=None, fallback=None
 ) -> jax.Array:
     """Oracle for kernels.forest_sample.forest_sample (same optional
     degenerate-cell pre-resolution as the kernel), stopping at the deepest
     lane (:func:`ref_forest_descent`)."""
     return ref_forest_descent(
-        cdf, table, left, right, xi, cell_first, fallback, depth
+        cdf, table, left, right, xi, cell_first, fallback
     )[0]
 
 
 def ref_forest_sample_batched(
-    cdf, table, left, right, dist_id, xi, cell_first=None, fallback=None,
-    depth: int = 64,
+    cdf, table, left, right, dist_id, xi, cell_first=None, fallback=None
 ) -> jax.Array:
     """Oracle for kernels.forest_sample.forest_sample_batched: lane q
     descends distribution dist_id[q]'s row with 2-D gathers (same optional
@@ -136,13 +96,13 @@ def ref_forest_sample_batched(
     resolve to 0 without descending — same contract as the kernel, so padded
     drains stay elementwise comparable."""
     return ref_forest_descent(
-        cdf, table, left, right, xi, cell_first, fallback, depth, dist_id
+        cdf, table, left, right, xi, cell_first, fallback, dist_id
     )[0]
 
 
 def ref_forest_sample_batched_streams(
     cdf, table, left, right, dist_id, counter, offset_bits,
-    cell_first=None, fallback=None, depth: int = 64,
+    cell_first=None, fallback=None,
 ):
     """Oracle for kernels.forest_sample.forest_sample_batched_streams: the
     same exact 24-bit fixed-point radical-inverse + rotation pipeline
@@ -152,7 +112,7 @@ def ref_forest_sample_batched_streams(
 
     xi = qmc_point(counter, offset_bits)
     idx = ref_forest_sample_batched(
-        cdf, table, left, right, dist_id, xi, cell_first, fallback, depth
+        cdf, table, left, right, dist_id, xi, cell_first, fallback
     )
     return idx, xi
 

@@ -4,7 +4,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import build_forest, normalize_weights, sample_binary
+from repro.core import (
+    MAX_DEPTH,
+    build_forest,
+    normalize_weights,
+    sample_binary,
+    sample_forest,
+)
+from repro.core.cdf import build_cdf
+from repro.core.forest2d import build_forest_rows, sample_forest_rows
 from repro.kernels import ops, ref
 from repro.kernels.cdf_scan import cdf_scan
 from repro.kernels.forest_delta import forest_delta, forest_delta_update
@@ -132,7 +140,7 @@ def test_forest_sample_kernel_deep_adversarial():
 
 
 def _fixed_trip_sample(cdf, table, left, right, dist_id, xi, cell_first,
-                       fallback, depth=64):
+                       fallback, depth=MAX_DEPTH):
     """The fixed-trip XLA descent that the ops ran before they stopped at
     the deepest lane: a 32-trip bisection over every lane, then a
     ``depth``-trip descent. Stacked (B, .) tables; one forest is a stack of
@@ -186,30 +194,61 @@ DESCENT_CASES = ("zipf", "spike_at_zero", "interior_ties", "dyadic_chain",
                  "all_leaves")
 
 
+def _one_forest_callers(op, f, w, m, xi):
+    """The drain of the forest ``f`` of weights ``w`` through ``op``."""
+    if op == "forest_sample":
+        return ops.forest_sample(f, xi, use_pallas=False)
+    if op == "core.sample_forest":
+        return sample_forest(f, xi)
+    from jax.sharding import Mesh
+
+    from repro.dist import forest as DF
+
+    D = max(d for d in (1, 2, 4, 8)
+            if d <= jax.device_count() and m % d == 0)
+    mesh = Mesh(np.array(jax.devices()[:D]), ("data",))
+    sf = DF.build_forest_sharded(w, m, mesh=mesh)
+    for k in ("cdf", "table", "cell_first", "fallback"):
+        assert np.array_equal(np.asarray(getattr(sf, k)),
+                              np.asarray(getattr(f, k))), k
+    return DF.sample_sharded(sf, xi, mesh=mesh,
+                             routed=op == "dist.sample_sharded_routed")
+
+
+ONE_FOREST_CALLERS = ("forest_sample", "core.sample_forest",
+                      "dist.sample_sharded_routed",
+                      "dist.sample_sharded_replicated")
+
+
 @pytest.mark.parametrize(
     "op,case",
     [(op, case)
-     for op in ("forest_sample", "forest_sample_batched",
-                "forest_sample_batched_streams")
+     for op in ONE_FOREST_CALLERS + (
+         "forest_sample_batched", "forest_sample_batched_streams",
+         "core.sample_forest_rows")
      for case in DESCENT_CASES + ("sentinel_lanes",)
-     if not (op == "forest_sample" and case == "sentinel_lanes")],
+     if not (op in ONE_FOREST_CALLERS + ("core.sample_forest_rows",)
+             and case == "sentinel_lanes")],
 )
 def test_xla_descent_matches_fixed_trip_loop(op, case):
-    """The XLA descent stops when its deepest lane is done, yet answers
-    elementwise as the fixed 64-trip descent behind a fixed 32-trip
-    bisection does: deep chains, flagged cells, sentinel lanes, and a batch
-    in which no lane descends at all."""
+    """Every caller of the one XLA descent (``core.sample.descend``) stops
+    when its deepest lane is done, yet answers elementwise as the fixed
+    ``MAX_DEPTH``-trip descent behind a fixed 32-trip bisection does: deep
+    chains, flagged cells, sentinel lanes, and a batch in which no lane
+    descends at all. The callers: the three ``ops`` drains, the core
+    sampler, the row-forest sampler and both sharded drains."""
     from repro.core.lds import qmc_point
-    from repro.pool.batched import build_forest_batched
+    from repro.pool.batched import batched_from_row_forest, build_forest_batched
 
     rng = np.random.default_rng(0)
     w, m = _descent_weights("zipf" if case == "sentinel_lanes" else case)
     w = np.asarray(w, np.float32)
     Q = 4096
-    if op == "forest_sample":
-        f = build_forest(jnp.asarray(normalize_weights(w)), m)
+    if op in ONE_FOREST_CALLERS:
+        wn = jnp.asarray(normalize_weights(w))
+        f = build_forest(wn, m)
         xi = jnp.asarray(rng.random(Q), jnp.float32)
-        got = ops.forest_sample(f, xi, use_pallas=False)
+        got = _one_forest_callers(op, f, wn, m, xi)
         stack = [x[None] for x in f]
         want = _fixed_trip_sample(*stack[:4], jnp.zeros(Q, jnp.int32), xi,
                                   *stack[4:])
@@ -217,9 +256,22 @@ def test_xla_descent_matches_fixed_trip_loop(op, case):
         return
     rows = [w, w[::-1], w] if case == "all_leaves" else [
         w, w[::-1], rng.random(w.size).astype(np.float32) + 1e-3]
-    bf = build_forest_batched(jnp.asarray(np.stack(rows)), m)
     lo = -1 if case == "sentinel_lanes" else 0
     did = jnp.asarray(rng.integers(lo, len(rows), Q), jnp.int32)
+    if op == "core.sample_forest_rows":
+        cdf_rows = jnp.stack([build_cdf(jnp.asarray(normalize_weights(r)))
+                              for r in rows])
+        rf = build_forest_rows(cdf_rows, m)
+        bf = batched_from_row_forest(rf, cdf_rows)
+        xi = jnp.asarray(rng.random(Q), jnp.float32)
+        got = sample_forest_rows(rf, did, xi)
+        # the row forest compares against its own lower bounds
+        want = _fixed_trip_sample(rf.data.reshape(len(rows), -1), bf.table,
+                                  bf.left, bf.right, did, xi, bf.cell_first,
+                                  bf.fallback)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    bf = build_forest_batched(jnp.asarray(np.stack(rows)), m)
     if op == "forest_sample_batched":
         xi = jnp.asarray(rng.random(Q), jnp.float32)
         got = ops.forest_sample_batched(bf, did, xi, use_pallas=False)
@@ -235,6 +287,32 @@ def test_xla_descent_matches_fixed_trip_loop(op, case):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     if case == "sentinel_lanes":
         assert np.all(np.asarray(got)[np.asarray(did) < 0] == 0)
+
+
+def test_descent_trip_cap_is_max_depth():
+    """One trip cap for every XLA descent. Zero weights after the last mass
+    whose float32 sum falls one ulp short of 1 leave 86 keys tied at
+    0.99999994 on a right spine, which the uniform at that key walks: 90
+    trips, more than 64. With the side tables off, the ``ops`` drain and
+    the core sampler must agree and answer valid intervals."""
+    from repro.core import depth_stats, sample_forest_with_stats
+
+    w = np.zeros(250)
+    w[[14, 103, 108, 144, 163]] = 2.0 ** -np.array([20, 6, 1, 18, 8])
+    f = build_forest(jnp.asarray(normalize_weights(w)), 1)
+    assert 64 < depth_stats(f)["max_depth"] < MAX_DEPTH
+    cdf = np.asarray(f.cdf)
+    xi = np.concatenate([np.unique(cdf[:-1]),
+                         np.random.default_rng(0).random(4096)])
+    xi = jnp.asarray(xi[xi < 1], jnp.float32)
+    assert int(np.asarray(sample_forest_with_stats(f, xi)[1]).max()) > 64
+    got = np.asarray(ops.forest_sample(f, xi, use_pallas=False,
+                                       degenerate=False))
+    core = np.asarray(sample_forest(f, xi, use_fallback=False))
+    np.testing.assert_array_equal(got, core)
+    x = np.asarray(xi)
+    assert np.all((got >= 0) & (got < cdf.size - 1))
+    assert np.all((cdf[got] <= x) & (x < cdf[got + 1]))
 
 
 @pytest.mark.parametrize("case", DESCENT_CASES)
